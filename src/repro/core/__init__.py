@@ -2,7 +2,6 @@
 from . import bitslice, compile_cache, cost_model, engine, isa  # noqa: F401
 from .engine import Engine, PimRelation  # noqa: F401
 
-# Local-dev persistent XLA compilation cache: no-op unless the operator
-# sets REPRO_JAX_CACHE_DIR (the CI bench job never does, so cold timings
-# stay honest).
-compile_cache.maybe_enable_persistent_cache()
+# Persistent XLA compilation cache: JAX_COMPILATION_CACHE_DIR if set,
+# else <checkout>/.jax_cache (see core.compile_cache).
+compile_cache.enable_persistent_cache()

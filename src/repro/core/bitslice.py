@@ -74,12 +74,15 @@ def pack_bits(values: np.ndarray, n_bits: int, n_words: int | None = None) -> np
     out = np.zeros((n_bits, n_words), dtype=np.uint32)
     if n == 0:
         return out
-    idx = np.arange(n, dtype=np.int64)
-    word = idx // WORD_BITS
-    shift = (idx % WORD_BITS).astype(np.uint32)
+    used = -(-n // WORD_BITS)
+    lanes = np.zeros(used * WORD_BITS, dtype=np.uint64)
+    lanes[:n] = v
+    lanes = lanes.reshape(used, WORD_BITS)        # row = one plane word
     for b in range(n_bits):
-        bits = ((v >> np.uint64(b)) & np.uint64(1)).astype(np.uint32)
-        np.add.at(out[b], word, bits << shift)  # slots are disjoint: add == or
+        bits = ((lanes >> np.uint64(b)) & np.uint64(1)).astype(np.uint8)
+        # 32 little-endian bits -> 4 bytes -> one little-endian word.
+        packed = np.packbits(bits, axis=1, bitorder="little")
+        out[b, :used] = packed.view("<u4")[:, 0]
     return out
 
 

@@ -59,10 +59,6 @@ class HwParams:
     # R-DDR media read rate per PIM module (crossbar reads are 16-bit and
     # slow [37]; this, not OpenCAPI 25 GB/s, bounds result readout).
     pim_media_read_bw: float = 2.5e9
-    # --- roofline constants for the TPU port (assignment-provided) ---
-    tpu_peak_flops: float = 197e12             # bf16 / chip
-    tpu_hbm_bw: float = 819e9                  # B/s / chip
-    tpu_ici_bw: float = 50e9                   # B/s / link
 
 
 DEFAULT_HW = HwParams()

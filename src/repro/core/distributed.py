@@ -72,7 +72,6 @@ from typing import Callable, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from . import engine as eng
 
@@ -109,8 +108,8 @@ def distributed_filter(mesh: Mesh, predicate_fn: Callable[..., jnp.ndarray],
     """
     ax = mesh_shard_axes(mesh, shard_axes)
 
-    @partial(shard_map, mesh=mesh, in_specs=(P(None, ax), P(ax)),
-             out_specs=P(ax), check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=(P(None, ax), P(ax)),
+             out_specs=P(ax), check_vma=False)
     def _run(planes, valid):
         return predicate_fn(planes) & valid
 
@@ -127,9 +126,9 @@ def distributed_filter_aggregate(mesh: Mesh,
     :func:`make_sum_where_program`."""
     ax = mesh_shard_axes(mesh, shard_axes)
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(None, ax), P(None, ax), P(ax)), out_specs=P(),
-             check_rep=False)
+             check_vma=False)
     def _run(filter_planes, agg_planes, valid):
         partial_val = program_fn(filter_planes, agg_planes, valid)
         return jax.lax.psum(partial_val, ax)
@@ -239,8 +238,8 @@ def shard_program_fn(local_fn: Callable, mesh: Mesh,
         "mat_cnt": {d: P(ax) for d in mat_items},
     }
 
-    @partial(shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-             check_rep=False)
+    @partial(jax.shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+             check_vma=False)
     def _run(planes: Dict[str, jnp.ndarray], valid: jnp.ndarray):
         raw = local_fn(planes, valid)
         job_pc = {k: jax.lax.psum(raw["job_pc"][k], ax) for k in pc_job_keys}

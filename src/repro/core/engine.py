@@ -156,9 +156,12 @@ def shift_planes(pa: jnp.ndarray, b: int, out_bits: int) -> jnp.ndarray:
     """(pa << b) truncated to ``out_bits`` planes (a multiply partial
     product before gating)."""
     w = tuple(pa.shape[1:])
-    return jnp.concatenate(
-        [jnp.zeros((b,) + w, U32), pa[: max(0, out_bits - b)]], axis=0
-    )[:out_bits]
+    n_zero = min(b, out_bits)
+    n_keep = max(0, min(pa.shape[0], out_bits - b))
+    # Never build a zero-sized stack: Mosaic has no zero-sized vector.
+    parts = ([jnp.zeros((n_zero,) + w, U32)] if n_zero else []) + \
+        ([pa[:n_keep]] if n_keep else [])
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
 
 
 def imm_planes(imm: int, n_bits: int, shape) -> jnp.ndarray:

@@ -359,10 +359,12 @@ class BitwiseEvaluator:
 
 
 def _reduce_minmax_bits(planes: jnp.ndarray, mask: jnp.ndarray,
-                        is_max: bool):
-    """Traceable MSB-first narrowing. Returns ((n_bits,) int32 result bits
-    LSB-first, found:bool) — the host assembles the exact value, and maps
-    found=False (empty selection) to None."""
+                        is_max: bool
+                        ) -> Tuple[List[jnp.ndarray], jnp.ndarray]:
+    """Traceable MSB-first narrowing. Returns (the result bits as a list
+    of int32 scalars, LSB-first; found:bool) — the host assembles the
+    exact value, and maps found=False (empty selection) to None. A list,
+    so the Pallas kernel can write the bits lane by lane."""
     n_bits = planes.shape[0]
     cand = mask
     bits: List[jnp.ndarray] = [None] * n_bits  # type: ignore[list-item]
@@ -377,7 +379,7 @@ def _reduce_minmax_bits(planes: jnp.ndarray, mask: jnp.ndarray,
             has = jnp.any(t != 0)
             bits[b] = jnp.logical_not(has).astype(jnp.int32)
             cand = jnp.where(has, t, cand & planes[b])
-    return jnp.stack(bits), jnp.any(mask != 0)
+    return bits, jnp.any(mask != 0)
 
 
 # --------------------------------------------------------------------------
@@ -943,6 +945,8 @@ class CompiledProgram:
         dataclasses.field(default_factory=dict)
     # The executable-cache signature (see :func:`program_signature`).
     signature: Optional[tuple] = None
+    # Whether the Pallas kernels run in interpret mode (off the TPU).
+    interpret: bool = False
 
     @property
     def n_dispatches(self) -> int:
@@ -1056,19 +1060,25 @@ class ProgramResult:
 
         Returns ``{attr: (count,) int array}`` in record order. The value
         buffer is the one output ``run_program`` leaves on device: only
-        the ``count``-row prefixes are sliced out before the host copy,
-        so readback traffic is O(selected records), not O(relation) —
-        the readout-reduction the subsystem exists for. Under a mesh the
+        the ``count``-row prefixes are copied to the host, so readback
+        traffic is O(selected records), not O(relation) — the
+        readout-reduction the subsystem exists for. Under a mesh the
         buffer is word-axis-sharded (shard s owns columns ``[s*cap,
-        (s+1)*cap)`` with its own count) and the per-shard prefixes are
-        stitched here — the mask never leaves the devices unsharded.
+        (s+1)*cap)`` with its own count); each device's piece is read
+        on its own, which works for any mesh axis type, and the
+        per-shard prefixes are stitched here — the mask never leaves the
+        devices unsharded.
         """
         vals = self._raw["mat_vals"][name]       # device-resident
         cnts = np.asarray(self._raw["mat_cnt"][name]).ravel()
         cap = vals.shape[1] // cnts.shape[0]
-        dense = np.concatenate(
-            [np.asarray(vals[:, s * cap:s * cap + int(cnts[s])])
-             for s in range(cnts.shape[0])], axis=1)
+        prefixes: Dict[int, np.ndarray] = {}
+        for piece in vals.addressable_shards:    # one shard's columns
+            s = (piece.index[1].start or 0) // cap
+            if s not in prefixes:                # replicas hold copies
+                prefixes[s] = _read_prefix(piece.data, int(cnts[s]))
+        dense = np.concatenate([prefixes[s] for s in range(cnts.shape[0])],
+                               axis=1)
         attrs = self._cp.mat_attrs[name]
         return {a: dense[i] for i, a in enumerate(attrs)}
 
@@ -1077,6 +1087,17 @@ class ProgramResult:
         same mask/scalar/materialized accessors, addressed by the
         query's OWN register names (translated through its slot)."""
         return QueryView(self, self._cp.query_slots[q])
+
+
+def _read_prefix(buf: jax.Array, count: int) -> np.ndarray:
+    """Host copy of the first ``count`` columns of a single-device
+    buffer. The device slice is rounded up to a power of two (within the
+    buffer) so distinct counts share a few slice executables; the
+    readback stays under twice the selected rows."""
+    if count == 0:
+        return np.zeros((buf.shape[0], 0), np.int32)
+    width = min(buf.shape[1], 1 << (count - 1).bit_length())
+    return np.asarray(buf[:, :width])[:, :count]
 
 
 class QueryView:
@@ -1140,7 +1161,8 @@ def compile_program(relation: eng.PimRelation,
     instrs = tuple(program)
     mask_outputs = tuple(mask_outputs)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        from repro.kernels.common import interpret_off_tpu
+        interpret = interpret_off_tpu()
 
     scalar_kinds: Dict[str, tuple] = {}
     mat_attrs: Dict[str, Tuple[str, ...]] = {}
@@ -1200,7 +1222,7 @@ def compile_program(relation: eng.PimRelation,
                            mat_attrs=mat_attrs,
                            query_slots=tuple(query_slots),
                            source_plane_counts=dict(widths),
-                           signature=sig)
+                           signature=sig, interpret=interpret)
 
 
 def run_program(cp: CompiledProgram, relation: eng.PimRelation) -> ProgramResult:
@@ -1246,7 +1268,7 @@ def _build_jnp_fn(instrs, mask_outputs, analysis: ProgramAnalysis,
             elif ins.kind == "ReduceMinMax":
                 bits, found = _reduce_minmax_bits(
                     ev.planes(ins.attr), ev.masks[ins.mask], ins.is_max)
-                mm_bits[ins.dest] = bits
+                mm_bits[ins.dest] = jnp.stack(bits)
                 mm_found[ins.dest] = found
             elif ins.kind == "Materialize":
                 mat_vals[ins.dest], mat_cnt[ins.dest] = \

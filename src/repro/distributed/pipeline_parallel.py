@@ -18,7 +18,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_apply(mesh: Mesh, stage_fn: Callable[[Any, jax.Array], jax.Array],
@@ -33,9 +32,9 @@ def pipeline_apply(mesh: Mesh, stage_fn: Callable[[Any, jax.Array], jax.Array],
     """
     n_stages = mesh.shape[axis]
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(P(axis), P(None)), out_specs=P(None),
-             check_rep=False)
+             check_vma=False)
     def run(params_stage, xs):
         params = jax.tree.map(lambda t: t[0], params_stage)  # my stage
         stage = jax.lax.axis_index(axis)

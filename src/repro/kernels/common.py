@@ -1,7 +1,15 @@
 """Helpers shared by every Pallas kernel in this package."""
 from __future__ import annotations
 
+import jax
 import numpy as np
+
+
+def interpret_off_tpu() -> bool:
+    """Pallas ``interpret`` for the backend JAX runs on now: compiled
+    kernels on a TPU, the interpreter anywhere else. Asked at trace
+    time, never at import."""
+    return jax.default_backend() != "tpu"
 
 
 def pick_block(w: int, requested: int) -> int:

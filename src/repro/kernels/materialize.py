@@ -7,20 +7,16 @@ records — the step that turns a PIM selection back into host-joinable
 rows (arXiv:2302.01675 / arXiv:2307.00658: PIM selection + host
 join/aggregation).
 
-One HBM tile-stream pass: each grid step stages one ``(rows, BLOCK_W)``
-tile of every attribute plane plus the mask into VMEM, transposes the
-planes back to per-record integers (bit ``b`` of word ``w`` lane ``l`` →
-record ``w*32+l``), and compacts the selected records to the front of
-its per-tile output block via an in-register prefix-sum scatter. The
-per-tile selected counts come back alongside; a cheap in-graph stitch
-(touching only the already-decoded values, never the planes again)
-gathers the per-tile prefixes into one dense array. Capacity equals the
-padded record count — the host reads back only ``count`` rows, which is
-the paper's readout-traffic win; device memory holds the (garbage) tail.
-
-The compaction scatter and the stitch's ``searchsorted`` are verified in
-interpret mode (like the program kernel's revisited accumulators);
-Mosaic lowering on real TPU is unexercised — see ROADMAP.
+Decoding is the one pass over the planes: bit ``b`` of plane word ``w``
+is record ``w*32 + b``, so each attribute decodes to a ``(32, W)`` int32
+block (row ``b``, column ``w``) with nothing but shifts, masks and ORs —
+per tile inside the Pallas kernel, full-width in the jnp lowering. The
+compaction that follows touches only the decoded values: a selected
+record's output slot is the popcount of the mask words before its own
+plus the popcount of the lower bits of its own word, and one scatter
+moves it there. Capacity equals the padded record count — the host
+reads back only ``count`` rows, which is the paper's readout-traffic
+win; device memory holds the (zero) tail.
 
 ``materialize`` is the standalone entry point (property-tested against
 the NumPy unpack+gather oracle); ``materialize_planes`` is the jnp
@@ -32,49 +28,61 @@ it behind the ``isa.Materialize`` instruction).
 from __future__ import annotations
 
 import functools
-from typing import List, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import pick_block as _pick_block, popcount as _popcount
+from .common import interpret_off_tpu, pick_block as _pick_block, \
+    popcount as _popcount
 
 U32 = jnp.uint32
 # Words per materialize tile: 512 words = 16 384 records; the per-tile
-# decoded block is (n_attrs, 16384) int32 = 64 KiB per attribute in VMEM,
-# well under budget for the handful of columns a query materializes.
+# decoded block is (n_attrs, 32, 512) int32 = 64 KiB per attribute in
+# VMEM, well under budget for the handful of columns a query materializes.
 BLOCK_W = 512
 WORD_BITS = 32
 
 
-def unpack_word_bits(words: jnp.ndarray) -> jnp.ndarray:
-    """(n_words,) uint32 -> (n_words*32,) uint32 of 0/1 record bits.
-
-    Record ``r`` lives at word ``r // 32`` bit ``r % 32`` (the
-    ``bitslice.pack_bits`` layout contract), so the lane axis is minor.
-    """
-    lanes = jnp.arange(WORD_BITS, dtype=U32)[None, :]
-    bits = (words[:, None] >> lanes) & U32(1)
-    return bits.reshape(-1)
-
-
-def decode_plane_values(planes: jnp.ndarray) -> jnp.ndarray:
-    """(n_bits, n_words) uint32 planes -> (n_words*32,) int32 values —
-    the bit-transpose half of the inverse of ``bitslice.pack_bits``."""
-    out = jnp.zeros(planes.shape[1] * WORD_BITS, jnp.int32)
+def decode_planes(planes: jnp.ndarray) -> jnp.ndarray:
+    """(n_bits, n_words) uint32 planes -> (32, n_words) int32 values:
+    element ``[b, w]`` is record ``w*32 + b`` — the bit-transpose half of
+    the inverse of ``bitslice.pack_bits``, usable inside kernel bodies."""
+    shape = (WORD_BITS, planes.shape[1])
+    shift = jax.lax.broadcasted_iota(U32, shape, 0)
+    out = jnp.zeros(shape, jnp.int32)
     for b in range(planes.shape[0]):
-        out = out | (unpack_word_bits(planes[b]).astype(jnp.int32) << b)
+        bit = (planes[b:b + 1] >> shift) & U32(1)
+        out = out | (bit.astype(jnp.int32) << b)
     return out
 
 
-def _compact(vals: jnp.ndarray, sel_bits: jnp.ndarray) -> jnp.ndarray:
-    """Stable stream compaction: selected records of ``vals`` (n_attrs,
-    n_rec) move to the front, in record order; the tail is zeros."""
-    seli = sel_bits.astype(jnp.int32)
-    pos = jnp.cumsum(seli) - seli                 # exclusive prefix sum
-    idx = jnp.where(sel_bits != 0, pos, vals.shape[1])
-    return jnp.zeros_like(vals).at[:, idx].set(vals, mode="drop")
+def _compact(vals: jnp.ndarray, mask: jnp.ndarray
+             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Stable stream compaction of decoded ``(n_attrs, 32, W)`` values
+    under the packed ``(W,)`` mask: the selected records move to the
+    front of an ``(n_attrs, W*32)`` buffer, in record order; the tail is
+    zeros. Returns ``(values, (1,) count)``.
+
+    Every record, in record order, adds its value (zero when unselected)
+    to slot "selected records before it" — an index that never
+    decreases, so the scatter is a sorted one, which XLA compiles in
+    about a second at any width (an unsorted scatter of a small relation
+    takes the TPU compiler tens of seconds)."""
+    n_attrs, _, w = vals.shape
+    counts = _popcount(mask).astype(jnp.int32)
+    before = jnp.cumsum(counts) - counts          # exclusive word prefix
+    b = jax.lax.broadcasted_iota(U32, (w, WORD_BITS), 1)   # [w, b] order
+    low = (U32(1) << b) - U32(1)                  # bits below b in a word
+    pos = before[:, None] + _popcount(mask[:, None] & low).astype(jnp.int32)
+    hit = ((mask[:, None] >> b) & U32(1)) != 0
+    rec = jnp.where(hit[None], jnp.transpose(vals, (0, 2, 1)), 0)
+    out = jnp.zeros((n_attrs, w * WORD_BITS), jnp.int32).at[
+        :, pos.reshape(-1)].add(rec.reshape(n_attrs, -1),
+                                indices_are_sorted=True,
+                                mode="promise_in_bounds")
+    return out, jnp.sum(counts)[None]
 
 
 def materialize_planes(attr_planes: Sequence[jnp.ndarray],
@@ -87,23 +95,16 @@ def materialize_planes(attr_planes: Sequence[jnp.ndarray],
     Returns ``((n_attrs, W*32) int32 values, (1,) int32 count)`` — the
     first ``count`` columns are the selected records, in record order.
     """
-    sel = unpack_word_bits(mask)
-    vals = jnp.stack([decode_plane_values(p) for p in attr_planes])
-    count = jnp.sum(sel.astype(jnp.int32))[None]
-    return _compact(vals, sel), count
+    return _compact(jnp.stack([decode_planes(p) for p in attr_planes]), mask)
 
 
 # --------------------------------------------------------------------------
-# Pallas kernel: per-tile decode + compaction, then an in-graph stitch
+# Pallas kernel: per-tile decode, then the compaction over decoded values
 # --------------------------------------------------------------------------
-def _materialize_kernel(stacked_ref, vals_ref, cnt_ref, *, attr_rows,
-                        mask_row):
+def _materialize_kernel(stacked_ref, vals_ref, *, attr_rows):
     allp = stacked_ref[...]                       # (rows, block_w) in VMEM
-    sel = unpack_word_bits(allp[mask_row])
-    vals = jnp.stack([decode_plane_values(allp[r0:r1])
-                      for r0, r1 in attr_rows])
-    vals_ref[...] = _compact(vals, sel)
-    cnt_ref[0, 0] = jnp.sum(_popcount(allp[mask_row]).astype(jnp.int32))
+    for k, (r0, r1) in enumerate(attr_rows):
+        vals_ref[k] = decode_planes(allp[r0:r1])
 
 
 def materialize_pallas(attr_planes: Sequence[jnp.ndarray],
@@ -112,54 +113,41 @@ def materialize_pallas(attr_planes: Sequence[jnp.ndarray],
                        ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Kernel-backed materialization: ONE pass over the attribute planes.
 
-    Same contract as :func:`materialize_planes`. The kernel emits
-    per-tile compacted blocks + per-tile counts; the stitch below turns
-    tile-local prefixes into one global prefix with a gather over the
-    decoded values only (the planes are never re-read).
+    Same contract as :func:`materialize_planes`. The kernel decodes each
+    tile of the planes; the compaction (a scatter, which Mosaic cannot
+    lower) runs on the decoded values in the surrounding jit — the
+    planes are never re-read.
     """
-    rows_list: List[jnp.ndarray] = []
     attr_rows: List[Tuple[int, int]] = []
     r0 = 0
     for p in attr_planes:
         attr_rows.append((r0, r0 + p.shape[0]))
-        rows_list.append(p)
         r0 += p.shape[0]
-    rows_list.append(mask[None])
-    stacked = jnp.concatenate(rows_list, axis=0)
+    stacked = jnp.concatenate(list(attr_planes), axis=0)
     rows, w = stacked.shape
     block_w = _pick_block(w, block_w)
-    n_tiles = w // block_w
-    block_r = block_w * WORD_BITS
     n_attrs = len(attr_rows)
 
     kernel = functools.partial(_materialize_kernel,
-                               attr_rows=tuple(attr_rows), mask_row=r0)
-    tile_vals, counts = pl.pallas_call(
+                               attr_rows=tuple(attr_rows))
+    vals = pl.pallas_call(
         kernel,
-        grid=(n_tiles,),
+        grid=(w // block_w,),
         in_specs=[pl.BlockSpec((rows, block_w), lambda i: (0, i))],
-        out_specs=[pl.BlockSpec((n_attrs, block_r), lambda i: (0, i)),
-                   pl.BlockSpec((1, 1), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((n_attrs, w * WORD_BITS), jnp.int32),
-                   jax.ShapeDtypeStruct((n_tiles, 1), jnp.int32)],
+        out_specs=pl.BlockSpec((n_attrs, WORD_BITS, block_w),
+                               lambda i: (0, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((n_attrs, WORD_BITS, w), jnp.int32),
         interpret=interpret,
     )(stacked)
-
-    counts = counts[:, 0]
-    cum = jnp.cumsum(counts)
-    cap = w * WORD_BITS
-    k = jnp.arange(cap, dtype=jnp.int32)
-    t = jnp.clip(jnp.searchsorted(cum, k, side="right"), 0, n_tiles - 1)
-    src = t * block_r + (k - (cum[t] - counts[t]))
-    out = tile_vals[:, jnp.clip(src, 0, cap - 1)]
-    return out, cum[-1:]
+    return _compact(vals, mask)
 
 
 # --------------------------------------------------------------------------
 # Standalone entry point (property-tested against the NumPy oracle)
 # --------------------------------------------------------------------------
 def materialize(planes, mask, backend: str = "jnp",
-                interpret: bool = True) -> Tuple[jnp.ndarray, int]:
+                interpret: Optional[bool] = None
+                ) -> Tuple[jnp.ndarray, int]:
     """Materialize one attribute (or a list of attributes) under ``mask``.
 
     planes: ``(n_bits, W)`` uint32 plane stack, or a sequence of them;
@@ -171,6 +159,8 @@ def materialize(planes, mask, backend: str = "jnp",
     plane_list = [jnp.asarray(planes)] if single else \
         [jnp.asarray(p) for p in planes]
     m = jnp.asarray(mask)
+    if interpret is None:
+        interpret = interpret_off_tpu()
     if backend == "pallas":
         vals, cnt = materialize_pallas(plane_list, m, interpret=interpret)
     else:

@@ -1,7 +1,8 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On a CPU backend (this container) the kernels run in interpret mode so the
-kernel bodies are validated end-to-end; on TPU they compile natively.
+Off the TPU the kernels run in interpret mode so the kernel bodies are
+validated end-to-end; on a TPU they compile natively. The choice is made
+at trace time, from the backend JAX runs on then.
 """
 from __future__ import annotations
 
@@ -12,39 +13,38 @@ import jax
 from . import bitpack as _bitpack
 from . import bitwise_filter as _filter
 from . import filter_aggregate as _fagg
-
-_INTERPRET = jax.default_backend() != "tpu"
+from .common import interpret_off_tpu as _interpret
 
 
 @partial(jax.jit, static_argnums=(1,))
 def predicate_eq_imm(planes, imm: int):
-    return _filter.eq_imm(planes, imm, interpret=_INTERPRET)
+    return _filter.eq_imm(planes, imm, interpret=_interpret())
 
 
 @partial(jax.jit, static_argnums=(1,))
 def predicate_cmp_imm(planes, imm: int):
-    return _filter.cmp_imm(planes, imm, interpret=_INTERPRET)
+    return _filter.cmp_imm(planes, imm, interpret=_interpret())
 
 
 @partial(jax.jit, static_argnums=(1, 2))
 def predicate_range(planes, lo: int, hi: int):
-    return _filter.range_mask(planes, lo, hi, interpret=_INTERPRET)
+    return _filter.range_mask(planes, lo, hi, interpret=_interpret())
 
 
 @partial(jax.jit, static_argnums=(3, 4))
 def fused_filter_sum(filter_planes, agg_planes, valid, lo: int, hi: int):
     return _fagg.filter_sum(filter_planes, agg_planes, valid, lo, hi,
-                            interpret=_INTERPRET)
+                            interpret=_interpret())
 
 
 @jax.jit
 def pack_mask(bits):
-    return _bitpack.bitpack(bits, interpret=_INTERPRET)
+    return _bitpack.bitpack(bits, interpret=_interpret())
 
 
 @jax.jit
 def unpack_mask(words):
-    return _bitpack.bitunpack(words, interpret=_INTERPRET)
+    return _bitpack.bitunpack(words, interpret=_interpret())
 
 
 def masked_sum(planes, mask):

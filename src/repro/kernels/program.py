@@ -9,16 +9,18 @@ reduce** of the program:
 * **Grouped popcounts** (``SumJob``): all ReduceSums sharing a source
   plane stack run as ONE job — each tile of the aggregate planes is
   popcounted once against the whole *stack* of group masks, and the
-  per-(group, bit) int32 partials accumulate into a VMEM-resident
-  accumulator block (constant output index map: the ``(1, n_pc)`` block is
-  revisited every grid step, zeroed at step 0). TPC-H Q1's 6 group masks
-  cost one read of each aggregate plane per tile instead of six — the
-  paper's grouped aggregation inside the array (arXiv:2307.00658 §4).
+  per-(group, bit) int32 lane partials accumulate into a VMEM-resident
+  accumulator block (constant output index map: the ``(n_pc, 128)`` block
+  is revisited every grid step, zeroed at step 0; its lanes are summed
+  once, after the last tile). TPC-H Q1's 6 group masks cost one read of
+  each aggregate plane per tile instead of six — the paper's grouped
+  aggregation inside the array (arXiv:2307.00658 §4).
 * **MIN/MAX** (``MinMaxJob``): per-tile MSB-first candidate narrowing at
   the instruction's program position, emitting ``width`` candidate bits +
-  a found flag per tile; the surrounding jit reduces them with the same
-  cross-candidate combine the distributed path applies across shards
-  (``core.distributed.combine_minmax_candidates``).
+  a found flag per tile (one ``(8, 128k)`` output block per tile, so every
+  block obeys Mosaic's (8, 128) tiling rule); the surrounding jit reduces
+  them with the same cross-candidate combine the distributed path applies
+  across shards (``core.distributed.combine_minmax_candidates``).
 * **Derived arithmetic** lowers carry-save (``core.program.plan_arith``):
   every Multiply's partial products reduce in a log-depth 3:2 compressor
   tree (``engine.csa_reduce``) followed by ONE carry-propagate pass, and
@@ -35,8 +37,8 @@ is honoured inside the kernel body via the precomputed ``frees`` table, so
 the per-tile VMEM working set tracks ``peak_live_planes``.
 
 VMEM budget per grid step: (source rows + peak live planes) x BLOCK_W x
-4 B plus the (1, n_pc) accumulator — the worst evaluated program (TPC-H
-Q1: ~55 source + ~90 live derived planes, ~200 accumulator columns) stays
+4 B plus the (n_pc, 128) accumulator — the worst evaluated program (TPC-H
+Q1: ~55 source + ~90 live derived planes, ~200 accumulator rows) stays
 under 1.5 MiB at BLOCK_W = 2048. The CSA tree transiently holds one
 multiply's ungated partial-product stacks (Q1's widest: 8 x 39 planes per
 tile) before compression collapses them; Mosaic is free to schedule the
@@ -76,6 +78,8 @@ from .common import pick_block as _pick_block, popcount as _popcount
 
 U32 = jnp.uint32
 BLOCK_W = 2048
+LANES = 128
+SUBLANES = 8
 
 
 def _program_kernel(stacked_ref, masks_ref, pc_ref, mm_ref, *, instrs,
@@ -99,6 +103,11 @@ def _program_kernel(stacked_ref, masks_ref, pc_ref, mm_ref, *, instrs,
     mm_at = {mj.exec_at: mj for mj in mm_jobs}
     batch_at = {b[0]: b for b in arith_batches}
     batched = {i for b in arith_batches for i in b}
+    # This tile's MIN/MAX candidate row: column c of a lane-aligned row,
+    # filled one scalar at a time by select (Mosaic has no scalar stores
+    # into a vector block).
+    mm_lane = jax.lax.broadcasted_iota(jnp.int32, (1, mm_ref.shape[1]), 1)
+    mm_row = jnp.zeros((1, mm_ref.shape[1]), jnp.int32)
 
     for i, ins in enumerate(instrs):
         if ins.kind in ("ReduceSum", "Materialize"):
@@ -110,8 +119,8 @@ def _program_kernel(stacked_ref, masks_ref, pc_ref, mm_ref, *, instrs,
             mj = mm_at[i]
             bits, found = _reduce_minmax_bits(
                 ev.planes(mj.attr)[:mj.width], ev.masks[mj.mask], mj.is_max)
-            mm_ref[0, mj.col_start:mj.col_start + mj.width] = bits
-            mm_ref[0, mj.col_start + mj.width] = found.astype(jnp.int32)
+            for c, v in enumerate(bits + [found.astype(jnp.int32)]):
+                mm_row = jnp.where(mm_lane == mj.col_start + c, v, mm_row)
         elif i in batch_at:
             # Independent derived-arith run: per-member CSA trees + ONE
             # batched carry-propagate pass (core.program.plan_arith).
@@ -122,26 +131,31 @@ def _program_kernel(stacked_ref, masks_ref, pc_ref, mm_ref, *, instrs,
             ev.execute(ins)
         for job in jobs_at.get(i, ()):
             # ONE read of each aggregate plane for the whole mask stack.
-            # Deliberately a per-bit loop rather than
-            # engine.reduce_sum_bits_grouped (the jnp lowering's form of
-            # the same contract): that would stage a (g, width, block_w)
-            # intermediate in VMEM; this bounds it to (g, block_w).
+            # Row ``col_start + b * g + k`` of the accumulator holds the
+            # 128 lane partials of (bit b, mask k); the caller sums the
+            # lanes once, after the last tile.
             p = ev.planes(job.attr)
             g = len(job.masks)
-            mstack = jnp.stack([ev.masks[m] for m in job.masks])
             for b in range(job.width):
-                pcb = jnp.sum(_popcount(mstack & p[b][None, :])
-                              .astype(jnp.int32), axis=1)
-                s = job.col_start + b * g
-                pc_ref[0, s:s + g] += pcb
+                for k, m in enumerate(job.masks):
+                    r = job.col_start + b * g + k
+                    pc_ref[r:r + 1, :] += _lane_fold(
+                        _popcount(ev.masks[m] & p[b]).astype(jnp.int32))
         for r in frees[i]:
             ev.free(r)
-    if not mm_jobs:
-        mm_ref[0, 0] = jnp.int32(0)
+    mm_ref[...] = jnp.broadcast_to(mm_row, mm_ref.shape)
     for k, name in enumerate(mask_outputs):
         masks_ref[k, :] = ev.masks[name]
     if not mask_outputs:
         masks_ref[0, :] = jnp.zeros_like(masks_ref[0, :])
+
+
+def _lane_fold(v: jax.Array) -> jax.Array:
+    """(block_w,) -> (1, 128): sum of the tile's lane-aligned slices."""
+    acc = v[:LANES]
+    for k in range(1, v.shape[0] // LANES):
+        acc = acc + v[k * LANES:(k + 1) * LANES]
+    return acc[None, :]
 
 
 def fused_program(stacked: jax.Array, *,
@@ -176,8 +190,11 @@ def fused_program(stacked: jax.Array, *,
     block_w = _pick_block(w, block_w)
     n_tiles = w // block_w
     grid = (n_tiles,)
-    n_pc = max(1, n_pc_cols)
-    n_mm = max(1, n_mm_cols)
+    # Both small outputs are laid out in whole (8, 128) tiles: the popcount
+    # accumulator as one row of lane partials per column, the MIN/MAX
+    # candidates as one 8-row block per tile (row 0 is read back).
+    n_pc = _round_up(max(1, n_pc_cols), SUBLANES)
+    n_mm = _round_up(max(1, n_mm_cols), LANES)
     n_mask_rows = max(1, len(mask_outputs))
 
     kernel = functools.partial(
@@ -185,16 +202,23 @@ def fused_program(stacked: jax.Array, *,
         valid_row=valid_row, mask_outputs=tuple(mask_outputs),
         sum_jobs=tuple(sum_jobs), mm_jobs=tuple(mm_jobs),
         frees=tuple(frees), arith_batches=tuple(arith_batches))
-    masks, pc_totals, mm_tiles = pl.pallas_call(
+    masks, pc_lanes, mm_blocks = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((rows, block_w), lambda i: (0, i))],
         out_specs=[pl.BlockSpec((n_mask_rows, block_w), lambda i: (0, i)),
-                   pl.BlockSpec((1, n_pc), lambda i: (0, 0)),
-                   pl.BlockSpec((1, n_mm), lambda i: (i, 0))],
+                   pl.BlockSpec((n_pc, LANES), lambda i: (0, 0)),
+                   pl.BlockSpec((SUBLANES, n_mm), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((n_mask_rows, w), U32),
-                   jax.ShapeDtypeStruct((1, n_pc), jnp.int32),
-                   jax.ShapeDtypeStruct((n_tiles, n_mm), jnp.int32)],
+                   jax.ShapeDtypeStruct((n_pc, LANES), jnp.int32),
+                   jax.ShapeDtypeStruct((n_tiles * SUBLANES, n_mm),
+                                        jnp.int32)],
         interpret=interpret,
     )(stacked)
+    pc_totals = jnp.sum(pc_lanes, axis=1)[None, :max(1, n_pc_cols)]
+    mm_tiles = mm_blocks[::SUBLANES, :max(1, n_mm_cols)]
     return masks, pc_totals, mm_tiles
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m

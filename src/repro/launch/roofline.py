@@ -88,15 +88,8 @@ class CellCosts:
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """Normalise Compiled.cost_analysis() across jax versions.
-
-    jax <= 0.4.33 returns a dict; 0.4.37 returns a list with one dict per
-    computation (usually length 1). Accept both and always hand back a dict.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
+    """Compiled.cost_analysis() as a plain dict (empty when XLA has none)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def costs_of(compiled) -> CellCosts:

@@ -103,10 +103,11 @@ def test_train_step_shards_on_debug_mesh():
         from repro.configs import get_smoke_config
         from repro.configs.common import ShapeConfig
         from repro.launch import steps as S
+        from repro.launch.mesh import make_debug_mesh
         from repro.launch.roofline import cost_analysis_dict
         cfg = get_smoke_config("olmoe-1b-7b")
         shape = ShapeConfig("t", 32, 8, "train")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_debug_mesh(2, 4)
         with mesh:
             b = S.build_train_step(cfg, shape, mesh)
             comp = b.fn.lower(*b.args).compile()
@@ -122,9 +123,10 @@ def test_serve_step_shards_on_debug_mesh():
         from repro.configs import get_smoke_config
         from repro.configs.common import ShapeConfig
         from repro.launch import steps as S
+        from repro.launch.mesh import make_debug_mesh
         cfg = get_smoke_config("gemma2-9b")
         shape = ShapeConfig("d", 64, 8, "decode")
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_debug_mesh(2, 4)
         with mesh:
             b = S.build_serve_step(cfg, shape, mesh)
             comp = b.fn.lower(*b.args).compile()

@@ -23,3 +23,19 @@ def test_filter_only_read_reduction_headline():
     spec = queries.get_query("Q14")     # single date-range filter
     rep = database.cost_report(db.run_pim(spec), sf_scale=1000 / 0.001)
     assert rep.read_reduction > 8      # 12-bit date attr vs 1 bit
+
+
+def test_compile_cache_dir_is_env_var_or_checkout():
+    """The persistent compile cache sits where JAX_COMPILATION_CACHE_DIR
+    says, else at a fixed <checkout>/.jax_cache."""
+    import os
+    from pathlib import Path
+
+    from repro.core import compile_cache
+
+    checkout = Path(__file__).resolve().parents[1]
+    assert compile_cache.DEFAULT_DIR == checkout / ".jax_cache"
+    want = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(
+        compile_cache.DEFAULT_DIR)
+    assert compile_cache.cache_dir() == want
+    assert compile_cache.enable_persistent_cache() == want
