@@ -48,6 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from repro import obs
 from . import bitslice, isa
 from . import engine as eng
 
@@ -1073,12 +1074,14 @@ class ProgramResult:
         cnts = np.asarray(self._raw["mat_cnt"][name]).ravel()
         cap = vals.shape[1] // cnts.shape[0]
         prefixes: Dict[int, np.ndarray] = {}
-        for piece in vals.addressable_shards:    # one shard's columns
-            s = (piece.index[1].start or 0) // cap
-            if s not in prefixes:                # replicas hold copies
-                prefixes[s] = _read_prefix(piece.data, int(cnts[s]))
-        dense = np.concatenate([prefixes[s] for s in range(cnts.shape[0])],
-                               axis=1)
+        with obs.span("mat_readback") as sp:
+            for piece in vals.addressable_shards:    # one shard's columns
+                s = (piece.index[1].start or 0) // cap
+                if s not in prefixes:                # replicas hold copies
+                    prefixes[s] = _read_prefix(piece.data, int(cnts[s]))
+            dense = np.concatenate(
+                [prefixes[s] for s in range(cnts.shape[0])], axis=1)
+            sp.set_metadata(rows=dense.shape[1], bytes=dense.nbytes)
         attrs = self._cp.mat_attrs[name]
         return {a: dense[i] for i, a in enumerate(attrs)}
 
@@ -1164,57 +1167,67 @@ def compile_program(relation: eng.PimRelation,
         from repro.kernels.common import interpret_off_tpu
         interpret = interpret_off_tpu()
 
-    scalar_kinds: Dict[str, tuple] = {}
-    mat_attrs: Dict[str, Tuple[str, ...]] = {}
-    mat_masks: List[str] = []
-    for ins in instrs:
-        if ins.kind == "ReduceSum":
-            scalar_kinds[ins.dest] = ("sum",)
-        elif ins.kind == "ReduceMinMax":
-            scalar_kinds[ins.dest] = ("minmax", ins.is_max)
-        elif ins.kind == "Materialize":
-            mat_attrs[ins.dest] = tuple(ins.attrs)
-            if ins.mask not in mat_masks:
-                mat_masks.append(ins.mask)
-    # Materialize masks are read out of the filter kernel (the pallas
-    # lowering feeds them to the materialize kernel), so pin them live.
-    keep = mask_outputs + tuple(m for m in mat_masks if m not in mask_outputs)
-    analysis = analyze_program(instrs, relation, keep=keep)
-    widths = {a: relation.width_of(a) for a in analysis.source_attrs}
-    plan = plan_reduces(instrs, analysis, widths)
-    arith = plan_arith(instrs, analysis, widths)
+    with obs.span("prepare", rel=relation.name) as sp:
+        scalar_kinds: Dict[str, tuple] = {}
+        mat_attrs: Dict[str, Tuple[str, ...]] = {}
+        mat_masks: List[str] = []
+        for ins in instrs:
+            if ins.kind == "ReduceSum":
+                scalar_kinds[ins.dest] = ("sum",)
+            elif ins.kind == "ReduceMinMax":
+                scalar_kinds[ins.dest] = ("minmax", ins.is_max)
+            elif ins.kind == "Materialize":
+                mat_attrs[ins.dest] = tuple(ins.attrs)
+                if ins.mask not in mat_masks:
+                    mat_masks.append(ins.mask)
+        # Materialize masks are read out of the filter kernel (the pallas
+        # lowering feeds them to the materialize kernel), so pin them live.
+        keep = mask_outputs + tuple(m for m in mat_masks
+                                    if m not in mask_outputs)
+        analysis = analyze_program(instrs, relation, keep=keep)
+        widths = {a: relation.width_of(a) for a in analysis.source_attrs}
+        plan = plan_reduces(instrs, analysis, widths)
+        arith = plan_arith(instrs, analysis, widths)
 
-    if mesh is not None:
-        from . import distributed as dist  # lazy: avoids import cycle
-        shard_axes = dist.mesh_shard_axes(mesh, shard_axes)
-
-    sig = program_signature(instrs, mask_outputs, backend, interpret,
-                            relation, widths, mesh, shard_axes)
-    fn = _FN_CACHE.get(sig)
-    if fn is None:
-        # Static verification rides the cache miss: every program is
-        # checked once, before the (much more expensive) XLA build, and
-        # warm-path compiles re-dispatch the cached fn with zero added
-        # work. Raises ProgramVerificationError on any error finding.
-        from repro.analysis import passes as _vp  # lazy: analysis imports us
-        _vp.verify_compile(instrs, relation, analysis, plan, arith,
-                           frozenset(keep), backend)
-        if backend == "pallas":
-            fn = _build_pallas_fn(instrs, mask_outputs, analysis, widths,
-                                  interpret, plan, arith)
-        else:
-            fn = _build_jnp_fn(instrs, mask_outputs, analysis, plan, arith)
         if mesh is not None:
-            fn = dist.shard_program_fn(
-                fn, mesh, shard_axes,
-                source_attrs=analysis.source_attrs,
-                mask_outputs=mask_outputs,
-                pc_job_keys=plan.job_keys(),
-                mm_items=tuple((d, k[1]) for d, k in scalar_kinds.items()
-                               if k[0] == "minmax"),
-                mat_items=tuple(mat_attrs))
-        fn = jax.jit(fn)
-        _FN_CACHE.put(sig, fn)
+            from . import distributed as dist  # lazy: avoids import cycle
+            shard_axes = dist.mesh_shard_axes(mesh, shard_axes)
+
+        sig = program_signature(instrs, mask_outputs, backend, interpret,
+                                relation, widths, mesh, shard_axes)
+        fn = _FN_CACHE.get(sig)
+        sp.set_metadata(hit=int(fn is not None))
+        if fn is None:
+            with obs.span("build", rel=relation.name):
+                # Static verification rides the cache miss: every program
+                # is checked once, before the (much more expensive) XLA
+                # build, and warm-path compiles re-dispatch the cached fn
+                # with zero added work. Raises ProgramVerificationError on
+                # any error finding.
+                from repro.analysis import passes as _vp  # lazy: analysis imports us
+                _vp.verify_compile(instrs, relation, analysis, plan, arith,
+                                   frozenset(keep), backend)
+                if backend == "pallas":
+                    fn = _build_pallas_fn(instrs, mask_outputs, analysis,
+                                          widths, interpret, plan, arith)
+                else:
+                    fn = _build_jnp_fn(instrs, mask_outputs, analysis, plan,
+                                       arith)
+                if mesh is not None:
+                    fn = dist.shard_program_fn(
+                        fn, mesh, shard_axes,
+                        source_attrs=analysis.source_attrs,
+                        mask_outputs=mask_outputs,
+                        pc_job_keys=plan.job_keys(),
+                        mm_items=tuple((d, k[1])
+                                       for d, k in scalar_kinds.items()
+                                       if k[0] == "minmax"),
+                        mat_items=tuple(mat_attrs))
+                # Named for its relation, so the trace's module line says
+                # which relation a device program belongs to.
+                fn.__name__ = fn.__qualname__ = f"pimdb_{relation.name}"
+                fn = jax.jit(fn)
+            _FN_CACHE.put(sig, fn)
 
     return CompiledProgram(instrs, mask_outputs, scalar_kinds, analysis,
                            plan, arith, backend, relation.layout.n_words, fn,
@@ -1233,9 +1246,12 @@ def run_program(cp: CompiledProgram, relation: eng.PimRelation) -> ProgramResult
     padded record count, and ``ProgramResult.materialized`` copies out
     only each shard's ``count``-row prefix."""
     planes = {a: relation.planes[a] for a in cp.analysis.source_attrs}
-    raw = dict(cp._fn(planes, relation.valid))
+    with obs.span("dispatch", rel=relation.name):
+        raw = dict(cp._fn(planes, relation.valid))
     mat_vals = raw.pop("mat_vals")
-    host = jax.device_get(raw)
+    with obs.span("readback", rel=relation.name) as sp:
+        host = jax.device_get(raw)
+        sp.set_metadata(bytes=sum(x.nbytes for x in jax.tree.leaves(host)))
     host["mat_vals"] = mat_vals
     return ProgramResult(cp, host, relation.n_records)
 

@@ -32,14 +32,13 @@ from __future__ import annotations
 
 import dataclasses
 import enum
-import threading
 import time
 import warnings
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
-from repro import analysis
+from repro import analysis, obs
 from repro.core import cost_model as cm
 from repro.core import engine as eng
 from repro.core import isa
@@ -258,9 +257,6 @@ class PimDatabase:
         # Counters of the most recent FUSED execute() call (dispatches,
         # plane reads, link dedup, walls) — None until one has run.
         self.last_batch_stats: Optional[Dict[str, object]] = None
-        # finish_query may accumulate host_s into shared batch stats from
-        # several host-pool workers at once.
-        self._stats_lock = threading.Lock()
         self.relations: Dict[str, eng.PimRelation] = {}
         for name, cols in tables.items():
             if S.SCHEMA[name].in_pim:
@@ -277,22 +273,40 @@ class PimDatabase:
         """Compile the FULL program for one relation: filter, group masks,
         aggregates. Returns (compiler, filter mask register,
         [(group label, {agg name: (kind, reg)})])."""
-        c = Compiler(rel, namespace=namespace)
-        is_agg_rel = (spec.kind == "full" and rel.name == spec.agg_relation)
-        mask_reg = c.compile_filter(pred, with_transform=not is_agg_rel)
-        group_regs: List[Tuple[str, Dict]] = []
-        if is_agg_rel:
-            for label, gpred in (spec.groups or [("all", None)]):
-                if gpred is None:
-                    gmask = mask_reg
-                else:
-                    gm = c.compile_pred(gpred)
-                    gmask = c.fresh("m")
-                    c.program.append(isa.BitwiseAnd(
-                        dest=gmask, src_a=mask_reg, src_b=gm))
-                group_regs.append((label, c.compile_aggregates(
-                    gmask, spec.aggregates)))
+        with obs.span("compile", rel=rel.name) as sp:
+            c = Compiler(rel, namespace=namespace)
+            is_agg_rel = (spec.kind == "full"
+                          and rel.name == spec.agg_relation)
+            mask_reg = c.compile_filter(pred, with_transform=not is_agg_rel)
+            group_regs: List[Tuple[str, Dict]] = []
+            if is_agg_rel:
+                for label, gpred in (spec.groups or [("all", None)]):
+                    if gpred is None:
+                        gmask = mask_reg
+                    else:
+                        gm = c.compile_pred(gpred)
+                        gmask = c.fresh("m")
+                        c.program.append(isa.BitwiseAnd(
+                            dest=gmask, src_a=mask_reg, src_b=gm))
+                    group_regs.append((label, c.compile_aggregates(
+                        gmask, spec.aggregates)))
+            sp.set_metadata(instrs=len(c.program))
         return c, mask_reg, group_regs
+
+    @staticmethod
+    def _compile_materialize(rel: eng.PimRelation, pred, cols,
+                             namespace: str = ""
+                             ) -> Tuple[Compiler, str, str]:
+        """Compile one relation's filter+materialize program for a host
+        stage's scan (a scan-all mask where ``pred`` is None). Returns
+        (compiler, filter mask register, materialize register)."""
+        with obs.span("compile", rel=rel.name) as sp:
+            c = Compiler(rel, namespace=namespace)
+            mask_reg = (c.compile_filter(pred, with_transform=False)
+                        if pred is not None else c.compile_scan_all())
+            mat_reg = c.compile_materialize(mask_reg, cols)
+            sp.set_metadata(instrs=len(c.program))
+        return c, mask_reg, mat_reg
 
     @staticmethod
     def _finalize_aggs(group_regs, read_scalar, read_reduce) -> Dict[str, Dict[str, object]]:
@@ -321,7 +335,10 @@ class PimDatabase:
                       ) -> RelationRun:
         cols = self.tables[rel_name]
         attrs = predicate_attrs(pred)
-        sels = _conjunct_selectivities(cols, pred, rel.n_records)
+        conjs = _conjuncts(pred)
+        with obs.span("relation_stats", rel=rel_name, conjuncts=len(conjs)):
+            sels = _conjunct_selectivities(cols, conjs)
+            selectivity = float(mask.mean()) if mask.size else 0.0
         agg_bits: List[int] = []
         if spec.kind == "full" and rel_name == spec.agg_relation:
             for a in spec.aggregates:
@@ -330,7 +347,7 @@ class PimDatabase:
                                  for x in predicate_attrs_of_expr(a.expr)]
         return RelationRun(
             n_records=rel.n_records, mask=mask, trace=trace,
-            selectivity=float(mask.mean()) if mask.size else 0.0,
+            selectivity=selectivity,
             filter_attr_bits=[rel.width_of(a) for a in attrs],
             filter_attr_sels=sels, agg_attr_bits=agg_bits,
             agg_plane_reads=cp.agg_plane_reads if cp else 0,
@@ -368,15 +385,17 @@ class PimDatabase:
             return []
         if len(specs) == 1 or engine is not Engine.FUSED:
             return [self._execute_one(s, engine) for s in specs]
-        pendings, _ = self.dispatch_batch(specs)
-        return [self.finish_query(p) for p in pendings]
+        with obs.span("execute", q=_names(specs)):
+            pendings, _ = self.dispatch_batch(specs)
+            return [self.finish_query(p) for p in pendings]
 
     def _execute_one(self, spec: Q.QuerySpec, engine: Engine) -> QueryResult:
-        if engine is Engine.ORACLE:
-            return self._execute_baseline(spec)
-        if spec.host is not None:
-            return self._execute_host(spec, engine)
-        return self._execute_pim(spec, engine)
+        with obs.query(spec.name), obs.span("execute"):
+            if engine is Engine.ORACLE:
+                return self._execute_baseline(spec)
+            if spec.host is not None:
+                return self._execute_host(spec, engine)
+            return self._execute_pim(spec, engine)
 
     def _execute_pim(self, spec: Q.QuerySpec, engine: Engine) -> QueryResult:
         """Mask/aggregate-scope execution on the PIM copy.
@@ -409,10 +428,11 @@ class PimDatabase:
                 res = prog.run_program(cp, rel)
                 dt = time.perf_counter() - t0
                 pim_s += dt
-                if group_regs:
-                    aggs.update(self._finalize_aggs(
-                        group_regs, res.scalar, res.scalar))
-                mask = res.mask(mask_reg)
+                with obs.span("unpack", rel=rel_name, records=rel.n_records):
+                    if group_regs:
+                        aggs.update(self._finalize_aggs(
+                            group_regs, res.scalar, res.scalar))
+                    mask = res.mask(mask_reg)
                 rel_stats[rel_name] = _single_relation_stats(c, cp, dt)
             else:
                 e = eng.Engine(rel, backend=self.backend)
@@ -457,10 +477,7 @@ class PimDatabase:
         rel_stats: Dict[str, Dict[str, object]] = {}
         for rel_name, pred, cols in pim_stage:
             rel = self.relations[rel_name]
-            c = Compiler(rel)
-            mask_reg = (c.compile_filter(pred, with_transform=False)
-                        if pred is not None else c.compile_scan_all())
-            mat_reg = c.compile_materialize(mask_reg, cols)
+            c, _, mat_reg = self._compile_materialize(rel, pred, cols)
             if fused:
                 cp = prog.compile_program(rel, c.program, mask_outputs=(),
                                           backend=self.backend,
@@ -480,8 +497,7 @@ class PimDatabase:
         pim_s = time.perf_counter() - t0
 
         t0 = time.perf_counter()
-        table = E.run_host_stage(host, E.ExecContext(materialized,
-                                                     self.tables))
+        table = self._host_stage(host, materialized, mat_rows)
         host_s = time.perf_counter() - t0
         stats = None
         if fused:
@@ -504,31 +520,30 @@ class PimDatabase:
         works: List[_BatchQuery] = []
         rel_programs: Dict[str, List[Tuple[tuple, tuple]]] = {}
         for qi, spec in enumerate(specs):
-            ns = f"q{qi}."
-            rels: List[_BatchRelation] = []
-            if spec.host is not None:
-                pim_stage, host = E.split_query(spec)
-                for rel_name, pred, cols in pim_stage:
-                    rel = self.relations[rel_name]
-                    c = Compiler(rel, namespace=ns)
-                    mask_reg = (c.compile_filter(pred, with_transform=False)
-                                if pred is not None else c.compile_scan_all())
-                    mat_reg = c.compile_materialize(mask_reg, cols)
-                    progs = rel_programs.setdefault(rel_name, [])
-                    rels.append(_BatchRelation(rel_name, pred, c, mask_reg,
-                                               [], mat_reg, len(progs)))
-                    progs.append((tuple(c.program), ()))
-                works.append(_BatchQuery(spec, host, rels))
-            else:
-                for rel_name, pred in spec.filters.items():
-                    rel = self.relations[rel_name]
-                    c, mask_reg, group_regs = self._compile_relation(
-                        rel, spec, pred, namespace=ns)
-                    progs = rel_programs.setdefault(rel_name, [])
-                    rels.append(_BatchRelation(rel_name, pred, c, mask_reg,
-                                               group_regs, None, len(progs)))
-                    progs.append((tuple(c.program), (mask_reg,)))
-                works.append(_BatchQuery(spec, None, rels))
+            with obs.query(spec.name):
+                ns = f"q{qi}."
+                rels: List[_BatchRelation] = []
+                if spec.host is not None:
+                    pim_stage, host = E.split_query(spec)
+                    for rel_name, pred, cols in pim_stage:
+                        rel = self.relations[rel_name]
+                        c, mask_reg, mat_reg = self._compile_materialize(
+                            rel, pred, cols, namespace=ns)
+                        progs = rel_programs.setdefault(rel_name, [])
+                        rels.append(_BatchRelation(rel_name, pred, c, mask_reg,
+                                                   [], mat_reg, len(progs)))
+                        progs.append((tuple(c.program), ()))
+                    works.append(_BatchQuery(spec, host, rels))
+                else:
+                    for rel_name, pred in spec.filters.items():
+                        rel = self.relations[rel_name]
+                        c, mask_reg, group_regs = self._compile_relation(
+                            rel, spec, pred, namespace=ns)
+                        progs = rel_programs.setdefault(rel_name, [])
+                        rels.append(_BatchRelation(rel_name, pred, c, mask_reg,
+                                                   group_regs, None, len(progs)))
+                        progs.append((tuple(c.program), (mask_reg,)))
+                    works.append(_BatchQuery(spec, None, rels))
         return works, rel_programs
 
     def dispatch_batch(self, specs: Sequence[Q.QuerySpec]
@@ -553,6 +568,11 @@ class PimDatabase:
         dedup, linked cache keys, walls) land in
         ``self.last_batch_stats`` and are returned.
         """
+        with obs.query(_names(specs)):
+            return self._dispatch_linked(specs)
+
+    def _dispatch_linked(self, specs: Sequence[Q.QuerySpec]
+                         ) -> Tuple[List[PendingQuery], Dict[str, object]]:
         t_all = time.perf_counter()
         works, rel_programs = self._compile_batch(specs)
 
@@ -562,7 +582,10 @@ class PimDatabase:
         pim_wall: Dict[str, float] = {}
         for rel_name, programs in rel_programs.items():
             rel = self.relations[rel_name]
-            lp = prog.link_programs(programs, relation=rel)
+            with obs.span("link", rel=rel_name,
+                          programs=len(programs)) as sp:
+                lp = prog.link_programs(programs, relation=rel)
+                sp.set_metadata(deduped=lp.n_deduped)
             cp = prog.compile_program(
                 rel, lp.instrs, mask_outputs=lp.mask_outputs,
                 backend=self.backend, mesh=self.mesh,
@@ -586,7 +609,6 @@ class PimDatabase:
             "n_dispatches": len(rel_programs),
             "pim_s": sum(pim_wall.values()),
             "demux_s": 0.0,
-            "host_s": 0.0,
             "wall_s": 0.0,
             "relations": {
                 r: {"n_programs": len(rel_programs[r]),
@@ -603,49 +625,52 @@ class PimDatabase:
 
         pendings: List[PendingQuery] = []
         demux_s = 0.0
-        for w in works:
-            t0 = time.perf_counter()
-            if w.host is not None:
-                materialized: Dict[str, E.HostTable] = {}
-                mat_rows: Dict[str, int] = {}
-                pim_s = 0.0
-                for br in w.rels:
-                    view = results[br.rel_name].query(br.slot)
-                    vals = view.materialized(br.mat_reg)
-                    materialized[br.rel_name] = E.HostTable(
-                        {a: np.asarray(v, np.int64)
-                         for a, v in vals.items()})
-                    mat_rows[br.rel_name] = materialized[br.rel_name].n_rows
-                    pim_s += share[br.rel_name]
-                pendings.append(PendingQuery(
-                    w.spec, Engine.FUSED, host=w.host,
-                    materialized=materialized, mat_rows=mat_rows,
-                    pim_s=pim_s, batch_stats=stats))
-            else:
-                rel_runs: Dict[str, RelationRun] = {}
-                aggs: Dict[str, Dict[str, object]] = {}
-                wall = 0.0
-                for br in w.rels:
-                    view = results[br.rel_name].query(br.slot)
-                    mask = view.mask(br.mask_reg)
-                    if br.group_regs:
-                        aggs.update(self._finalize_aggs(
-                            br.group_regs, view.scalar, view.scalar))
-                    rel = self.relations[br.rel_name]
-                    rel_runs[br.rel_name] = self._relation_run(
-                        rel, br.rel_name, w.spec, br.pred, mask,
-                        list(br.compiler.program),
-                        cp=compiled[br.rel_name])
-                    wall += share[br.rel_name]
-                res = QueryResult(
-                    spec=w.spec, engine=Engine.FUSED, aggregates=aggs,
-                    relations=rel_runs, pim_s=wall,
-                    wall_s=wall + time.perf_counter() - t0,
-                    batch_stats=stats)
-                pendings.append(PendingQuery(w.spec, Engine.FUSED,
-                                             result=res, pim_s=wall,
-                                             batch_stats=stats))
-            demux_s += time.perf_counter() - t0
+        with obs.span("demux", queries=len(works)):
+            for w in works:
+                t0 = time.perf_counter()
+                if w.host is not None:
+                    materialized: Dict[str, E.HostTable] = {}
+                    mat_rows: Dict[str, int] = {}
+                    pim_s = 0.0
+                    for br in w.rels:
+                        view = results[br.rel_name].query(br.slot)
+                        vals = view.materialized(br.mat_reg)
+                        materialized[br.rel_name] = E.HostTable(
+                            {a: np.asarray(v, np.int64)
+                             for a, v in vals.items()})
+                        mat_rows[br.rel_name] = materialized[br.rel_name].n_rows
+                        pim_s += share[br.rel_name]
+                    pendings.append(PendingQuery(
+                        w.spec, Engine.FUSED, host=w.host,
+                        materialized=materialized, mat_rows=mat_rows,
+                        pim_s=pim_s, batch_stats=stats))
+                else:
+                    rel_runs: Dict[str, RelationRun] = {}
+                    aggs: Dict[str, Dict[str, object]] = {}
+                    wall = 0.0
+                    for br in w.rels:
+                        view = results[br.rel_name].query(br.slot)
+                        rel = self.relations[br.rel_name]
+                        with obs.span("unpack", rel=br.rel_name,
+                                      records=rel.n_records):
+                            mask = view.mask(br.mask_reg)
+                            if br.group_regs:
+                                aggs.update(self._finalize_aggs(
+                                    br.group_regs, view.scalar, view.scalar))
+                        rel_runs[br.rel_name] = self._relation_run(
+                            rel, br.rel_name, w.spec, br.pred, mask,
+                            list(br.compiler.program),
+                            cp=compiled[br.rel_name])
+                        wall += share[br.rel_name]
+                    res = QueryResult(
+                        spec=w.spec, engine=Engine.FUSED, aggregates=aggs,
+                        relations=rel_runs, pim_s=wall,
+                        wall_s=wall + time.perf_counter() - t0,
+                        batch_stats=stats)
+                    pendings.append(PendingQuery(w.spec, Engine.FUSED,
+                                                 result=res, pim_s=wall,
+                                                 batch_stats=stats))
+                demux_s += time.perf_counter() - t0
 
         stats["demux_s"] = demux_s
         stats["wall_s"] = time.perf_counter() - t_all
@@ -659,16 +684,21 @@ class PimDatabase:
         if pending.result is not None:
             return pending.result
         t0 = time.perf_counter()
-        table = E.run_host_stage(
-            pending.host, E.ExecContext(pending.materialized, self.tables))
+        with obs.query(pending.spec.name):
+            table = self._host_stage(pending.host, pending.materialized,
+                                     pending.mat_rows)
         host_s = time.perf_counter() - t0
-        if pending.batch_stats is not None:
-            with self._stats_lock:
-                pending.batch_stats["host_s"] = (
-                    pending.batch_stats.get("host_s", 0.0) + host_s)
         return QueryResult.from_table(
             pending.spec, table, pending.pim_s, host_s, pending.mat_rows,
             engine=pending.engine, batch_stats=pending.batch_stats)
+
+    def _host_stage(self, host, materialized: Dict[str, "E.HostTable"],
+                    mat_rows: Dict[str, int]) -> "E.HostTable":
+        with obs.span("host_stage", rows_in=sum(mat_rows.values())) as sp:
+            table = E.run_host_stage(host, E.ExecContext(materialized,
+                                                         self.tables))
+            sp.set_metadata(rows_out=table.n_rows)
+        return table
 
     # -- baseline (numpy scan oracle) ----------------------------------------
     def _execute_baseline(self, spec: Q.QuerySpec) -> QueryResult:
@@ -747,7 +777,10 @@ class PimDatabase:
         order: List[str] = []
         for m in mutations:
             name = dml_mod.mutation_relation(m)
-            st = self.dml_state(name).apply(m)
+            with obs.span("dml.mutate", rel=name) as sp:
+                st = self.dml_state(name).apply(m)
+                sp.set_metadata(rows=st.n_rows,
+                                cells_written=st.cells_written)
             entry = stats.setdefault(name, {
                 "n_mutations": 0, "n_rows": 0, "n_instructions": 0,
                 "cycles": 0, "cells_written": 0})
@@ -781,15 +814,16 @@ class PimDatabase:
         versions: Dict[str, int] = {}
         for name in rel_names:
             d = self._dml[name]
-            version = max(d.rel.version,
-                          self.relations[name].version) + 1
-            rel = dataclasses.replace(d.rel, version=version)
-            if self.mesh is not None:
-                rel = rel.shard(self.mesh, self.shard_axes)
-            self.relations[name] = rel
-            d.rel = rel
-            self.tables[name] = d.live_columns()
-            versions[name] = version
+            with obs.span("dml.publish", rel=name, rows=len(d.slot_of)):
+                version = max(d.rel.version,
+                              self.relations[name].version) + 1
+                rel = dataclasses.replace(d.rel, version=version)
+                if self.mesh is not None:
+                    rel = rel.shard(self.mesh, self.shard_axes)
+                self.relations[name] = rel
+                d.rel = rel
+                self.tables[name] = d.live_columns()
+                versions[name] = version
         return versions
 
     def dml_row_ops(self) -> Dict[str, float]:
@@ -850,6 +884,10 @@ class PimDatabase:
         return self._execute_baseline(spec.filter_only())
 
 
+def _names(specs: Sequence[Q.QuerySpec]) -> str:
+    return "+".join(s.name for s in specs)
+
+
 def _empty_batch_stats() -> Dict[str, object]:
     return {"n_queries": 0, "n_dispatches": 0, "pim_s": 0.0,
             "demux_s": 0.0, "host_s": 0.0, "wall_s": 0.0, "relations": {}}
@@ -901,9 +939,12 @@ def predicate_attrs_of_expr(e) -> List[str]:
     return res
 
 
-def _conjunct_selectivities(cols, pred, n) -> List[float]:
+def _conjuncts(pred) -> list:
+    return list(pred.ps) if isinstance(pred, And) else [pred]
+
+
+def _conjunct_selectivities(cols, conjs) -> List[float]:
     """Per-conjunct pass fractions in evaluation order (baseline model)."""
-    conjs = list(pred.ps) if isinstance(pred, And) else [pred]
     sels = []
     for c in conjs:
         try:

@@ -32,6 +32,7 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Tuple
 
+from repro import obs
 from repro.core import program as prog
 from repro.db.database import Engine, PimDatabase, QueryResult
 from repro.faults.model import TransientDispatchError
@@ -132,10 +133,11 @@ class QueryService:
         result cache by construction, since ``PimDatabase.apply`` bumps
         every mutated relation's version on publish).
         """
+        t_call = time.perf_counter()
         loop = self._bind_loop()
         self.batcher.flush_now()
         stats = await loop.run_in_executor(
-            self._dispatch_pool, self.db.apply, list(mutations))
+            self._dispatch_pool, self._run_apply, list(mutations), t_call)
         self.n_mutations += sum(s["n_mutations"] for s in stats.values())
         return stats
 
@@ -194,7 +196,20 @@ class QueryService:
             for r in window:
                 self._reject(r, e)
 
+    def _run_apply(self, mutations, t_call: float):
+        with obs.span("serve.apply",
+                      queued_s=time.perf_counter() - t_call):
+            return self.db.apply(mutations)
+
     def _run_window(self, window: List[_Request]) -> None:
+        t0 = time.perf_counter()
+        queued = [t0 - r.t_submit for r in window]
+        with obs.span("serve.window", q="+".join(r.spec.name for r in window),
+                      n=len(window), queued_s=sum(queued),
+                      max_queued_s=max(queued, default=0.0)):
+            self._dispatch_window(window)
+
+    def _dispatch_window(self, window: List[_Request]) -> None:
         try:
             fm = self.faults
             if self.engine is not Engine.FUSED:
@@ -241,7 +256,8 @@ class QueryService:
                 rs["plane_reads"] for rs in stats["relations"].values())
             for r, p in zip(window, pendings):
                 if p.needs_host:
-                    self._host_pool.submit(self._finish_host, r, p)
+                    self._host_pool.submit(self._finish_host, r, p,
+                                           time.perf_counter())
                 else:
                     self._resolve(r, p.result)
         except Exception as e:                   # noqa: BLE001
@@ -256,11 +272,13 @@ class QueryService:
             except Exception as e:              # noqa: BLE001
                 self._reject(r, e)
 
-    def _finish_host(self, req: _Request, pending) -> None:
-        try:
-            self._resolve(req, self.db.finish_query(pending))
-        except Exception as e:                   # noqa: BLE001
-            self._reject(req, e)
+    def _finish_host(self, req: _Request, pending, t_handoff: float) -> None:
+        with obs.span("serve.host", q=pending.spec.name,
+                      queued_s=time.perf_counter() - t_handoff):
+            try:
+                self._resolve(req, self.db.finish_query(pending))
+            except Exception as e:               # noqa: BLE001
+                self._reject(req, e)
 
     def _resolve(self, req: _Request, res: QueryResult) -> None:
         self.cache.put(req.key, res)

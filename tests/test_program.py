@@ -76,6 +76,20 @@ def test_single_dispatch_per_relation(db):
     assert cp.paper_cycles() > 0
 
 
+@pytest.mark.parametrize("rel_name", ["lineitem", "part"])
+def test_program_module_named_after_its_relation(db, rel_name):
+    """The jitted program is ``pimdb_<relation>``, so a trace's module
+    line says which relation a device program belongs to."""
+    spec = next(q for q in queries.all_queries()
+                if rel_name in q.filters and q.host is None)
+    rel = db.relations[rel_name]
+    c, mask_reg, _ = db._compile_relation(rel, spec, spec.filters[rel_name])
+    cp = prog.compile_program(rel, c.program, mask_outputs=(mask_reg,))
+    planes = {a: rel.planes[a] for a in cp.analysis.source_attrs}
+    text = cp._fn.lower(planes, rel.valid).as_text()
+    assert f"@jit_pimdb_{rel_name}" in text
+
+
 def test_liveness_shrinks_live_planes(db):
     """Register liveness must find dead intermediates to reuse: the peak
     simultaneously-live plane count is below the no-reuse total."""
