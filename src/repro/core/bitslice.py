@@ -129,7 +129,19 @@ def pack_mask(mask: np.ndarray, n_words: int | None = None) -> np.ndarray:
 
 
 def unpack_mask(words: np.ndarray, n_records: int) -> np.ndarray:
-    return unpack_bits(np.asarray(words)[None, :], n_records).astype(bool)
+    """Inverse of :func:`pack_mask` -> writeable bool array (n_records,).
+
+    Record ``r`` is bit ``r % 32`` of word ``r // 32``, LSB first: over
+    the words' little-endian bytes that is little-endian bit order, so
+    one linear ``np.unpackbits`` pass decodes the whole mask.
+    """
+    words = np.ascontiguousarray(words, dtype="<u4")
+    capacity = WORD_BITS * words.shape[0]
+    if not 0 <= n_records <= capacity:
+        raise ValueError(f"{n_records} records out of range for the "
+                         f"{capacity} bits of {words.shape[0]} words")
+    bits = np.unpackbits(words.view(np.uint8), count=n_records, bitorder="little")
+    return bits.view(bool)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -36,6 +36,22 @@ def test_pack_unpack_roundtrip_padded_words(n, bits, seed):
     assert (got == vals[sel]).all()
 
 
+def _gather_unpack_mask(words, n):
+    """The per-record gather that ``unpack_mask`` replaced: the oracle."""
+    words = np.asarray(words, dtype=np.uint32)
+    idx = np.arange(n, dtype=np.int64)
+    bits = (words[idx // 32] >> (idx % 32).astype(np.uint32)) & np.uint32(1)
+    return bits.astype(bool)
+
+
+def _check_unpack_mask(words, n):
+    got = bitslice.unpack_mask(words, n)
+    assert got.dtype == np.bool_ and got.shape == (n,)
+    assert got.flags.writeable
+    np.testing.assert_array_equal(got, _gather_unpack_mask(words, n))
+    return got
+
+
 @settings(max_examples=30, deadline=None)
 @given(st.integers(1, 5000), st.integers(0, 2**32))
 def test_mask_roundtrip(n, seed):
@@ -43,6 +59,71 @@ def test_mask_roundtrip(n, seed):
     m = rng.random(n) < 0.3
     packed = bitslice.pack_mask(m)
     assert (bitslice.unpack_mask(packed, n) == m).all()
+    _check_unpack_mask(packed, n)
+    # Random stray 1-bits past n in the tile-padded words must not read back.
+    stray = rng.random(packed.shape[0] * 32) < 0.5
+    stray[:n] = False
+    dirty = packed | bitslice.pack_mask(stray, packed.shape[0])
+    assert (_check_unpack_mask(dirty, n) == m).all()
+
+
+@pytest.mark.parametrize("n", [0, 1, 31, 33, 63, 1000, bitslice.TILE_RECORDS - 5,
+                               bitslice.TILE_RECORDS, bitslice.TILE_RECORDS + 7])
+def test_unpack_mask_matches_gather(n):
+    """Lengths off the word and tile grid, and n == 0, against the gather."""
+    rng = np.random.default_rng(n)
+    words = rng.integers(0, 2**32, bitslice.pad_words(n), dtype=np.uint32)
+    _check_unpack_mask(words, n)
+
+
+def test_unpack_mask_ignores_stray_bits_beyond_n():
+    """Words at the tile-padded capacity, every bit past n set to 1."""
+    n = 70_001
+    rng = np.random.default_rng(7)
+    m = rng.random(n) < 0.02
+    words = bitslice.pack_mask(m, bitslice.pad_words(n))
+    tail = np.ones(words.shape[0] * 32, dtype=bool)
+    tail[:n] = False
+    words |= bitslice.pack_mask(tail, words.shape[0])
+    assert words[-1] == np.uint32(0xFFFFFFFF)
+    got = _check_unpack_mask(words, n)
+    np.testing.assert_array_equal(got, m)
+
+
+@pytest.mark.parametrize("layout", ["big_endian", "strided"])
+def test_unpack_mask_byte_order_and_strides(layout):
+    rng = np.random.default_rng(11)
+    n = 5_000
+    words = rng.integers(0, 2**32, bitslice.pad_words(n), dtype=np.uint32)
+    if layout == "big_endian":
+        given_words = words.astype(">u4")
+        assert given_words.dtype.byteorder == ">"
+    else:
+        wide = np.zeros((words.shape[0], 3), dtype=np.uint32)
+        wide[:, 1] = words
+        given_words = wide[:, 1]
+        assert not given_words.flags.c_contiguous
+    got = bitslice.unpack_mask(given_words, n)
+    np.testing.assert_array_equal(got, _gather_unpack_mask(words, n))
+
+
+def test_unpack_mask_sf1_lineitem():
+    """An SF 1 lineitem-sized mask: 6,001,215 records, 2% selected."""
+    n = 6_001_215
+    rng = np.random.default_rng(2024)
+    m = rng.random(n) < 0.02
+    words = bitslice.pack_mask(m)
+    got = _check_unpack_mask(words, n)
+    np.testing.assert_array_equal(got, m)
+
+
+@pytest.mark.parametrize("n", [4 * 32 + 1, 5 * 32, -1])
+def test_unpack_mask_rejects_counts_out_of_range(n):
+    """Past the words' bits np.unpackbits would pad with zeros, and a
+    negative count would trim from the end: both raise instead."""
+    words = np.zeros(4, dtype=np.uint32)
+    with pytest.raises(ValueError):
+        bitslice.unpack_mask(words, n)
 
 
 def test_padding_is_tile_aligned():
