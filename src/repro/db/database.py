@@ -49,6 +49,37 @@ from . import schema as S
 from .compiler import And, Compiler, predicate_attrs
 
 
+class _CostStat:
+    """A :class:`RelationRun` field that only the cost report reads.  A
+    value given at construction is kept; ``None`` (the default) is
+    computed on first read, with its sibling, by
+    ``RelationRun._fill_cost_stats`` and cached on the instance."""
+
+    def __set_name__(self, owner, name):
+        self.key = "_" + name
+
+    def __get__(self, run, owner=None):
+        if run is None:
+            return None                  # the dataclass field's default
+        if run.__dict__[self.key] is None:
+            run._fill_cost_stats()
+        return run.__dict__[self.key]
+
+    def __set__(self, run, value):
+        run.__dict__[self.key] = value
+
+
+@dataclasses.dataclass(frozen=True)
+class FilterSource:
+    """What a run's deferred cost statistics read: the relation's name,
+    its filter's conjuncts, and the host columns those conjuncts read as
+    the query saw them (``publish`` re-points ``PimDatabase.tables`` at
+    fresh arrays, so the references stay a consistent snapshot)."""
+    rel: str
+    conjuncts: Tuple
+    columns: Dict[str, np.ndarray]
+
+
 @dataclasses.dataclass
 class RelationRun:
     """Per-relation outcome of a query.
@@ -57,17 +88,40 @@ class RelationRun:
     reduce plan: aggregate-plane tile reads per pass with grouped
     popcounts vs one read per ReduceSum/MinMax (the pre-grouping
     executor) — zero on eager/baseline runs, which have no plan.
+
+    ``selectivity`` (the mask's pass fraction) and ``filter_attr_sels``
+    (each filter conjunct's pass fraction, by a NumPy scan of the host
+    columns) feed only :func:`cost_report`.  Left ``None``, they are
+    computed from ``filter_source`` on first read, so serving a query
+    never pays for them.
     """
     n_records: int
     mask: np.ndarray
     trace: List[isa.PimInstruction]
-    selectivity: float
     filter_attr_bits: List[int]
-    filter_attr_sels: List[float]
     agg_attr_bits: List[int]
     agg_plane_reads: int = 0
     agg_plane_reads_ungrouped: int = 0
     n_reduce_jobs: int = 0
+    selectivity: float = _CostStat()
+    filter_attr_sels: List[float] = _CostStat()
+    filter_source: Optional[FilterSource] = dataclasses.field(
+        default=None, repr=False)
+
+    def _fill_cost_stats(self) -> None:
+        src = self.filter_source
+        if src is None:
+            raise ValueError("RelationRun has neither its cost statistics "
+                             "nor a filter_source to compute them from")
+        with obs.span("relation_stats", rel=src.rel,
+                      conjuncts=len(src.conjuncts)):
+            if self.__dict__["_filter_attr_sels"] is None:
+                self.filter_attr_sels = _conjunct_selectivities(
+                    src.columns, src.conjuncts)
+            if self.__dict__["_selectivity"] is None:
+                mask = self.mask
+                self.selectivity = float(mask.mean()) if mask.size else 0.0
+        self.filter_source = None        # release the columns
 
 
 class Engine(enum.Enum):
@@ -335,10 +389,8 @@ class PimDatabase:
                       ) -> RelationRun:
         cols = self.tables[rel_name]
         attrs = predicate_attrs(pred)
-        conjs = _conjuncts(pred)
-        with obs.span("relation_stats", rel=rel_name, conjuncts=len(conjs)):
-            sels = _conjunct_selectivities(cols, conjs)
-            selectivity = float(mask.mean()) if mask.size else 0.0
+        source = FilterSource(rel_name, tuple(_conjuncts(pred)),
+                              {a: cols[a] for a in attrs if a in cols})
         agg_bits: List[int] = []
         if spec.kind == "full" and rel_name == spec.agg_relation:
             for a in spec.aggregates:
@@ -347,9 +399,8 @@ class PimDatabase:
                                  for x in predicate_attrs_of_expr(a.expr)]
         return RelationRun(
             n_records=rel.n_records, mask=mask, trace=trace,
-            selectivity=selectivity,
             filter_attr_bits=[rel.width_of(a) for a in attrs],
-            filter_attr_sels=sels, agg_attr_bits=agg_bits,
+            agg_attr_bits=agg_bits, filter_source=source,
             agg_plane_reads=cp.agg_plane_reads if cp else 0,
             agg_plane_reads_ungrouped=(cp.agg_plane_reads_ungrouped
                                        if cp else 0),

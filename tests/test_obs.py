@@ -78,13 +78,15 @@ def test_spans_of_execute_batch_and_apply(db, tmp_path):
     n_lineitem = db.relations["lineitem"].n_records
     _start(tmp_path)
     try:
-        db.execute(q6)
+        r6 = db.execute(q6)
         db.execute(q3)
         pendings, _ = db.dispatch_batch([q6, q3])
         [db.finish_query(p) for p in pendings]
         take = {a: np.asarray(c[:8])
                 for a, c in db.tables["lineitem"].items()}
         db.apply([dml.Insert("lineitem", take)])
+        with obs.span("test.report"):    # marks the report's stretch
+            db.report(r6)
     finally:
         jax.profiler.stop_trace()
     spans = _spans(tmp_path)
@@ -98,8 +100,8 @@ def test_spans_of_execute_batch_and_apply(db, tmp_path):
     # Q6's path: its children account for it, each carrying its query.
     q6_kids = {s["name"] for s in spans if s["parent"] == "execute"
                and ex[0]["start"] <= s["start"] < ex[0]["end"]}
-    assert {"compile", "prepare", "dispatch", "readback", "unpack",
-            "relation_stats"} <= q6_kids
+    assert {"compile", "prepare", "dispatch", "readback",
+            "unpack"} <= q6_kids
     unpack = _named(spans, "unpack")
     assert unpack[0]["parent"] == "execute"
     assert unpack[0]["attrs"]["q"] == "Q6"
@@ -109,8 +111,15 @@ def test_spans_of_execute_batch_and_apply(db, tmp_path):
     assert all(s["attrs"]["hit"] == 1 for s in _named(spans, "prepare")
                if s["attrs"]["q"] == "Q6")
     assert all(s["attrs"]["instrs"] > 0 for s in _named(spans, "compile"))
-    assert all(s["attrs"]["conjuncts"] >= 1
-               for s in _named(spans, "relation_stats"))
+    # The cost report's statistics are computed when the report reads
+    # them, never on the served path: not under any execute or demux.
+    stats = _named(spans, "relation_stats")
+    served = _named(spans, "execute") + _named(spans, "demux")
+    assert not any(e["start"] <= s["start"] < e["end"]
+                   for s in stats for e in served)
+    assert [s["parent"] for s in stats] == ["test.report"]
+    assert stats[0]["attrs"]["rel"] == "lineitem"
+    assert all(s["attrs"]["conjuncts"] >= 1 for s in stats)
     mat = _named(spans, "mat_readback")
     assert mat and all(s["attrs"]["rows"] >= 0 for s in mat)
 
