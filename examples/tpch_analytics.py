@@ -29,7 +29,7 @@ import numpy as np
 from repro import dml
 from repro.core import bitslice
 from repro.db import Engine, database, queries, tpch
-from repro.launch.serve import serve_trace
+from repro.serve.replay import serve_trace
 
 
 def main():

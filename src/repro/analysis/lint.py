@@ -7,11 +7,12 @@ a scan-all program per PIM relation, LINKED multi-query programs
 (every adjacent pair plus a leading triple of the queries sharing each
 relation, built exactly the way ``PimDatabase.execute`` builds them:
 namespaced compile, ``core.program.link_programs``), and the serving
-frontend's admission-window fusions (the coalesced windows the
-``serve_concurrent`` bench and CLI traces dispatch) — and runs every
-analysis pass over each program on all three backend schedules ("trace",
-"jnp", "pallas"). No XLA executable is built: only the static front half
-of the compile pipeline runs, so the whole sweep takes seconds.
+frontend's admission-window fusions (the coalesced windows that
+concurrent submissions and ``repro.serve.replay`` traces dispatch) —
+and runs every analysis pass over each program on all three backend
+schedules ("trace", "jnp", "pallas"). No XLA executable is built: only
+the static front half of the compile pipeline runs, so the whole sweep
+takes seconds.
 
 Exit status is non-zero when any error-severity diagnostic is produced
 (or any warning, under ``--strict``); CI runs this as a job so a change
@@ -111,18 +112,19 @@ def collect_linked_programs(db: PimDatabase) -> List[Program]:
 
 def collect_serve_programs(db: PimDatabase) -> List[Program]:
     """Admission-window fusion products of the serving frontend: the
-    windows ``repro.serve.QueryService`` actually dispatches when the
-    benchmark/CLI traces replay — each window's coalesced spec set
-    (duplicates collapse onto one in-flight dispatch, exactly as the
-    service's cache-key coalescing does) linked per relation.  These are
-    the programs reachable through ``PimDatabase.execute`` that the
-    static pair/triple sweep above does not cover."""
+    windows ``repro.serve.QueryService`` actually dispatches when
+    concurrent clients and replayed traces submit — each window's
+    coalesced spec set (duplicates collapse onto one in-flight
+    dispatch, exactly as the service's cache-key coalescing does)
+    linked per relation.  These are the programs reachable through
+    ``PimDatabase.execute`` that the static pair/triple sweep above does
+    not cover."""
     from repro.core import program as prog
     from repro.db.database import Engine
     from repro.serve.cache import spec_cache_key
 
-    # The serve_concurrent bench wave + the CLI default trace's
-    # distinct-query window.
+    # A concurrency-8 wave over six distinct queries, and the
+    # distinct-query window of repro.serve.replay's default trace.
     windows = [
         ("bench-wave", ["Q1", "Q6", "Q14", "Q3", "Q12", "Q19",
                         "Q6", "Q1"]),

@@ -116,7 +116,8 @@ _LOWERING_KINDS = ("csa_compress", "carry_propagate", "copy_through")
 # carries the Table 4 requests for the same arithmetic, so charging the
 # lowering would double-count. Kept as an explicit table (not a constant
 # 0) so a future internal kind that genuinely should cost cycles flips
-# the q1_arith bench's cycles-unchanged gate instead of hiding here.
+# the q1_arith cycles-unchanged check of tests/test_counters.py instead
+# of hiding here.
 _LOWERING_CYCLE_COST = {"csa_compress": 0, "carry_propagate": 0,
                         "copy_through": 0}
 

@@ -214,7 +214,7 @@ def csa_tree_levels(k: int) -> int:
     """3:2 compressor levels needed to reduce ``k`` addends to 2.
 
     Mirrors ``csa_reduce``'s loop exactly (full triples compress 3 -> 2,
-    the 0-2 leftover terms pass through) so the CI-gated depth counter
+    the 0-2 leftover terms pass through) so the tested depth counter
     tracks the real lowering; change the two together.
     """
     levels = 0

@@ -1,8 +1,8 @@
 """NumPy mutable-table oracle for DML parity checks.
 
 An independent reimplementation of the mutation semantics over plain
-column arrays — no bit-planes, no slots, no allocator. Tests and the
-``htap_stream`` bench drive the same logical mutation stream through a
+column arrays — no bit-planes, no slots, no allocator. The tests
+drive the same logical mutation stream through a
 :class:`MutableTable` and through ``PimDatabase.apply``, then compare
 query results bit-for-bit; the two bookkeeping paths share nothing but
 the mutation specs, so agreement is evidence, not tautology.

@@ -18,7 +18,7 @@ Two policies:
     reused until the cursor comes around again. Inserts spread across
     every row of the append segment, flattening the profile by roughly
     ``capacity / working-set`` — the wear-leveling model this package
-    ships (and the ``htap_stream`` bench gates at <= 0.5x first-fit).
+    ships (and ``tests/test_counters.py`` holds at <= 0.5x first-fit).
 
 The allocator keeps per-slot cell-write counters and a *logical* event
 log (slot-free, so it can be replayed through a fresh allocator of the

@@ -12,8 +12,8 @@ windows), a circuit-breaker trip, and the half-open recovery probe.
 Everything is scheduled, nothing is sampled: the same seed and scale
 factor produce the same injection coordinates, the same detection
 rounds, and the same recovery counters — which is what lets
-``check_regression.py`` gate the ``chaos_soak`` bench row on exact
-counts.
+``tests/test_counters.py`` hold the ``chaos_soak`` counters to their
+measured values.
 
 Invariants asserted every round (folded into the report's ``parity``):
 

@@ -28,7 +28,7 @@ engine must survive:
 
 Everything is deterministic: fault *placement* is chosen by the caller
 (chaos harness / tests), never sampled internally, so every chaos run
-is exactly replayable and the bench row it produces is gateable.
+is exactly replayable and the counters it produces are testable.
 """
 from __future__ import annotations
 

@@ -1,68 +1,31 @@
-"""Serving drivers: LM decode loop + PIMDB query-trace replay.
+"""PIMDB query-trace replay through the async serving frontend.
 
-``--mode lm`` (default): builds the pjit'd decode step (launch/steps.py
-shards the cache per DESIGN §6 — batch over dp, long sequences over
-'model'), greedy-decodes a batch of requests, and reports tokens/s.
+Replays a query trace (comma-separated TPC-H names, with ``xN`` repeats,
+e.g. ``Q1,Q6x3,Q3``) through ``repro.serve.QueryService`` at fixed
+concurrency, and reports qps, p50/p99 latency, dispatch/plane-read
+totals and cache behaviour.  ``--compare`` also runs the same trace as a
+sequential ``db.execute`` loop for the speedup, and exits 1 if any
+result differs.
 
-``--mode db``: replays a query trace (comma-separated TPC-H names, with
-``xN`` repeats, e.g. ``Q1,Q6x3,Q3``) through the async
-``repro.serve.QueryService`` at fixed concurrency, and reports qps,
-p50/p99 latency, dispatch/plane-read totals and cache behaviour — the
-throughput rung of the ROADMAP serving item.  ``--compare`` also runs
-the same trace as a sequential ``db.execute`` loop for the speedup.
+    PYTHONPATH=src python -m repro.serve.replay --sf 0.005 --compare
 """
 from __future__ import annotations
 
 import argparse
 import asyncio
-import dataclasses
 import os
 import sys
+import threading
 import time
 
-import jax
-import jax.numpy as jnp
-import numpy as np
+from repro.db import Engine, PimDatabase, queries, tpch
+from repro.serve import QueryService
 
-from repro.configs import SHAPES, get_config, get_smoke_config
-from repro.configs.common import ShapeConfig
-from repro.launch.mesh import make_debug_mesh, make_production_mesh
-from repro.models.lm import LM
-
-
-def serve(cfg, batch: int, prompt_len: int, gen_len: int, mesh=None):
-    model = LM(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    max_len = prompt_len + gen_len
-    cache = model.init_cache(batch, max_len)
-    extra = None
-    if cfg.block_pattern == "encdec":
-        extra = jax.random.normal(jax.random.PRNGKey(2),
-                                  (batch, 64, cfg.d_model), jnp.bfloat16)
-        _, cross = model.encode(params, extra)
-        cache["cross"] = cross
-
-    step_fn = jax.jit(model.decode_step, donate_argnums=(1,))
-    tokens = jax.random.randint(jax.random.PRNGKey(1), (batch, 1),
-                                0, cfg.vocab)
-    out_tokens = [np.asarray(tokens)]
-    t0 = time.time()
-    for pos in range(max_len - 1):
-        logits, cache = step_fn(params, cache, tokens, jnp.int32(pos))
-        tokens = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        out_tokens.append(np.asarray(tokens))
-    dt = time.time() - t0
-    seq = np.concatenate(out_tokens, axis=1)
-    return seq, batch * (max_len - 1) / dt
-
-
-# -- PIMDB query-trace replay ------------------------------------------------
 DEFAULT_TRACE = "Q1,Q6,Q14,Q3,Q12,Q6,Q14,Q1,Q6,Q19,Q3,Q6,Q14,Q12,Q1,Q6"
 
 
 def parse_trace(trace: str):
     """``Q1,Q6x3,Q3`` -> [Q1, Q6, Q6, Q6, Q3] QuerySpecs."""
-    from repro.db import queries
     specs = []
     for tok in trace.split(","):
         tok = tok.strip()
@@ -77,8 +40,6 @@ def serve_trace(db, specs, *, concurrency: int = 8, max_window: int = 8,
                 max_wait_s: float = 0.002, cache_capacity: int = 256):
     """Replay ``specs`` through a QueryService at fixed concurrency.
     Returns (results in trace order, service stats, wall seconds)."""
-    from repro.serve import QueryService
-
     async def run():
         svc = QueryService(db, max_window=max_window, max_wait_s=max_wait_s,
                            cache_capacity=cache_capacity,
@@ -104,8 +65,6 @@ def _arm_watchdog(timeout_s: float):
     kill the whole process with exit code 124 (the ``timeout(1)``
     convention) — a wedged event loop or dispatch worker must fail CI,
     never hang it.  Returns the started timer (daemon thread)."""
-    import threading
-
     def die():
         sys.stderr.write(
             f"serve replay exceeded --timeout-s={timeout_s}; aborting\n")
@@ -118,8 +77,21 @@ def _arm_watchdog(timeout_s: float):
     return t
 
 
-def serve_db_main(args) -> None:
-    from repro.db import Engine, PimDatabase, tpch
+def main(argv=None) -> None:
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--sf", type=float, default=0.005)
+    ap.add_argument("--seed", type=int, default=7)
+    ap.add_argument("--backend", default="jnp",
+                    choices=("jnp", "pallas"))
+    ap.add_argument("--trace", default=DEFAULT_TRACE)
+    ap.add_argument("--concurrency", type=int, default=8)
+    ap.add_argument("--window", type=int, default=8)
+    ap.add_argument("--max-wait-ms", type=float, default=2.0)
+    ap.add_argument("--compare", action="store_true")
+    ap.add_argument("--timeout-s", type=float, default=600.0,
+                    help="hard wall-clock limit for the replay "
+                         "(exit 124 on expiry; 0 disables)")
+    args = ap.parse_args(argv)
 
     watchdog = _arm_watchdog(args.timeout_s) if args.timeout_s else None
     tables = tpch.generate(sf=args.sf, seed=args.seed)
@@ -163,34 +135,6 @@ def serve_db_main(args) -> None:
               f"service speedup {seq_wall / wall:.2f}x (bit-parity ok)")
     if watchdog is not None:
         watchdog.cancel()
-
-
-def main():
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--mode", choices=("lm", "db"), default="lm")
-    ap.add_argument("--arch", default="qwen2-0.5b")
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--gen-len", type=int, default=16)
-    ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--sf", type=float, default=0.005)
-    ap.add_argument("--seed", type=int, default=7)
-    ap.add_argument("--backend", default="jnp",
-                    choices=("jnp", "pallas"))
-    ap.add_argument("--trace", default=DEFAULT_TRACE)
-    ap.add_argument("--concurrency", type=int, default=8)
-    ap.add_argument("--window", type=int, default=8)
-    ap.add_argument("--max-wait-ms", type=float, default=2.0)
-    ap.add_argument("--compare", action="store_true")
-    ap.add_argument("--timeout-s", type=float, default=600.0,
-                    help="hard wall-clock limit for --mode db replay "
-                         "(exit 124 on expiry; 0 disables)")
-    args = ap.parse_args()
-    if args.mode == "db":
-        serve_db_main(args)
-        return
-    cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    seq, tps = serve(cfg, args.batch, 1, args.gen_len)
-    print(f"decoded {seq.shape} at {tps:.1f} tok/s")
 
 
 if __name__ == "__main__":

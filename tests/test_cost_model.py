@@ -1,4 +1,5 @@
-"""Paper Table 4 / §6 analytics: cycle formulas, energy, endurance."""
+"""Paper Table 4 / §6 analytics: cycle formulas, energy, endurance, and
+the bands of Figs. 8a, 8b, 11, 15 and Table 5 over every query."""
 import pytest
 
 from repro.core import cost_model as cm
@@ -80,3 +81,78 @@ def test_baseline_cacheline_model():
     sel = cm.baseline_scan_bytes(10**6, [32, 32], [0.001, 1.0])
     assert sel < full
     assert sel >= 10**6 * 4        # first column always fully scanned
+
+
+# --------------------------------------------------------------------------
+# Paper figures at paper scale: every query's cost report in the band the
+# paper reports (SF 0.003 generated, projected to the paper's SF 1000).
+# --------------------------------------------------------------------------
+FIG_SF = 0.003
+FIG_SCALE = 1000 / FIG_SF
+RRAM_ENDURANCE = 1e12
+
+
+@pytest.fixture(scope="module")
+def paper_reports():
+    from repro.db import database, queries, tpch
+    db = database.PimDatabase(tpch.generate(sf=FIG_SF, seed=42))
+    return {spec.name: (spec, database.cost_report(
+        db.execute(spec.filter_only()), FIG_SCALE))
+        for spec in queries.all_queries()}
+
+
+def _in_band(lo, hi, value):
+    return lo <= value <= hi
+
+
+def _fig8a(spec, rep):
+    # paper: filter-only speedups 0.82x-18x
+    return spec.kind != "filter" or _in_band(0.7, 30.0, rep.speedup)
+
+
+def _fig8b(spec, rep):
+    # paper: full-query speedups 56x-608x
+    return spec.kind != "full" or _in_band(40.0, 900.0, rep.speedup)
+
+
+def _table5(spec, rep):
+    # filter queries end in a column transform and reduce nothing; full
+    # queries spend more cycles reducing than filtering
+    b = rep.cycles
+    if spec.kind == "filter":
+        return b["col_transform"] > 0 and b["reduce_col"] == 0
+    return b["reduce_col"] + b["reduce_row"] > b["filter"]
+
+
+def _fig11(spec, rep):
+    # paper: energy savings 0.88x-15.3x (filter), 0.81x-12x (full)
+    lo, hi = (0.5, 40.0) if spec.kind == "filter" else (0.7, 30.0)
+    return _in_band(lo, hi, rep.energy_saving)
+
+
+def _fig15(spec, rep):
+    # every query stays within RRAM endurance except Q22_sub, whose small
+    # relation concentrates back-to-back executions on the same cells
+    within = rep.endurance_ops_per_cell_10y < RRAM_ENDURANCE
+    return within == (spec.name != "Q22_sub")
+
+
+FIGURES = {"fig8a": _fig8a, "fig8b": _fig8b, "table5": _table5,
+           "fig11": _fig11, "fig15": _fig15}
+
+
+@pytest.mark.parametrize("figure,only,skip", [
+    pytest.param("fig8a", None, (), id="fig8a"),
+    pytest.param("fig8b", None, (), id="fig8b"),
+    pytest.param("table5", None, (), id="table5"),
+    pytest.param("fig11", None, (), id="fig11"),
+    pytest.param("fig15", None, ("Q6",), id="fig15"),
+    pytest.param("fig15", "Q6", (), id="fig15-Q6", marks=pytest.mark.xfail(
+        strict=True, reason="Q6 needs 1.42e12 writes per cell over 10 "
+        "years at SF 1000; the paper puts it within RRAM's 1e12")),
+])
+def test_paper_figure_bands(paper_reports, figure, only, skip):
+    check = FIGURES[figure]
+    names = [only] if only else [n for n in paper_reports if n not in skip]
+    out = [n for n in names if not check(*paper_reports[n])]
+    assert not out, f"{figure}: out of band: {out}"

@@ -65,7 +65,7 @@ def test_q1_one_read_per_aggregate_plane(tables):
     assert agg_jobs and all(len(j.masks) == n_groups for j in agg_jobs)
     # The headline: >= n_groups x fewer aggregate-plane reads per pass.
     assert cp.agg_plane_reads_ungrouped >= n_groups * cp.agg_plane_reads
-    # ...and the stats surface through the harness for the bench gate.
+    # ...and the stats surface through the query result.
     rr = db.run_pim(spec, fused=True).relations["lineitem"]
     assert rr.agg_plane_reads == cp.agg_plane_reads
     assert rr.agg_plane_reads_ungrouped == cp.agg_plane_reads_ungrouped

@@ -1,7 +1,8 @@
 """Async serving frontend: result cache (hit/miss/version-invalidation),
 admission batcher (size vs timeout flush), concurrent-submit parity vs
-sequential ``execute`` on jnp+pallas, backpressure, and an 8-device mesh
-subprocess smoke test."""
+sequential ``execute`` on jnp+pallas, backpressure, every query through
+``submit`` after an RF1/RF2 refresh pair against the oracle, trace
+replay, and an 8-device mesh subprocess smoke test."""
 import asyncio
 
 import numpy as np
@@ -14,6 +15,7 @@ from repro.db import queries, tpch
 from repro.db.database import Engine, PimDatabase
 from repro.serve import (AdmissionBatcher, QueryService, ResultCache,
                          spec_cache_key)
+from repro.serve.replay import parse_trace, serve_trace
 from repro.serve.service import _pct
 
 # Same generator parameters as test_fusion.py / test_api.py so the
@@ -327,6 +329,122 @@ def test_closed_service_rejects_promptly(db):
 
     stats = asyncio.run(run())
     assert stats["errors"] == 1 and stats["inflight"] == 0
+
+
+# --------------------------------------------------------------------------
+# The served path after a refresh: every query through submit() equals
+# the oracle over the refreshed tables
+# --------------------------------------------------------------------------
+N_REFRESH = 30      # orders inserted by RF1 and deleted by RF2 (1%)
+
+
+def _refresh_pair(tables):
+    """An RF1/RF2 pair over ``tables``: RF1 inserts copies of some orders
+    under fresh keys, with copies of their lineitems; RF2 deletes other
+    orders and all their lineitems, by key."""
+    from repro.db.compiler import Col, InSet
+
+    rng = np.random.default_rng(SEED)
+    orders, lineitem = tables["orders"], tables["lineitem"]
+    keys = np.asarray(orders["o_orderkey"])
+    picked = rng.permutation(keys.size)
+    src, gone = picked[:N_REFRESH], picked[N_REFRESH:2 * N_REFRESH]
+    new_key = dict(zip(keys[src].tolist(),
+                       range(int(keys.max()) + 1,
+                             int(keys.max()) + 1 + N_REFRESH)))
+    new_orders = {a: np.asarray(c)[src] for a, c in orders.items()}
+    new_orders["o_orderkey"] = np.asarray(
+        [new_key[k] for k in keys[src].tolist()])
+    lk = np.asarray(lineitem["l_orderkey"])
+    lines = np.flatnonzero(np.isin(lk, keys[src]))
+    new_lines = {a: np.asarray(c)[lines] for a, c in lineitem.items()}
+    new_lines["l_orderkey"] = np.asarray(
+        [new_key[k] for k in lk[lines].tolist()])
+    dead = tuple(int(k) for k in keys[gone])
+    rf1 = [dml.Insert("orders", new_orders),
+           dml.Insert("lineitem", new_lines)]
+    rf2 = [dml.Delete("orders", pred=InSet(Col("o_orderkey"), dead)),
+           dml.Delete("lineitem", pred=InSet(Col("l_orderkey"), dead))]
+    return rf1, rf2
+
+
+@pytest.fixture(scope="module")
+def refreshed():
+    """One service applies the pair, then answers every query; the oracle
+    is a fresh database over the tables a ``MutableTable`` yields after
+    the same mutations."""
+    tables = tpch.generate(sf=SF, seed=SEED)
+    served = PimDatabase(tables)
+    rf1, rf2 = _refresh_pair(tables)
+    mirror = {rel: dml.MutableTable(tables[rel])
+              for rel in ("orders", "lineitem")}
+    for m in rf1 + rf2:
+        mirror[m.relation].apply(m)
+
+    async def run():
+        answers = {}
+        async with QueryService(served, max_window=1,
+                                cache_capacity=0) as svc:
+            await svc.apply(rf1)
+            await svc.apply(rf2)
+            for spec in queries.all_queries():
+                try:
+                    answers[spec.name] = await svc.submit(spec)
+                except Exception as e:       # raised by its own case
+                    answers[spec.name] = e
+        return answers
+
+    answers = asyncio.run(run())
+    oracle = PimDatabase({**tables, **{rel: t.columns()
+                                       for rel, t in mirror.items()}})
+    return served, answers, oracle
+
+
+@pytest.mark.parametrize("qname", [q.name for q in queries.all_queries()])
+def test_submit_after_refresh_matches_oracle(refreshed, qname):
+    served, answers, oracle = refreshed
+    got = answers[qname]
+    if isinstance(got, Exception):
+        raise got
+    spec = queries.get_query(qname)
+    want = oracle.execute(spec, engine=Engine.ORACLE)
+    assert got.rows == want.rows
+    assert got.aggregates == want.aggregates
+    if spec.host is not None:
+        return
+    for rel in spec.filters:
+        mask = np.asarray(got.relations[rel].mask)
+        if rel in ("orders", "lineitem"):
+            # Served masks are in slot order; read them in logical order.
+            d = served.dml_state(rel)
+            mask = mask[[d.slot_of[i] for i in d.live_ids()]]
+        np.testing.assert_array_equal(mask, want.relations[rel].mask,
+                                      err_msg=rel)
+
+
+# --------------------------------------------------------------------------
+# Trace replay (python -m repro.serve.replay)
+# --------------------------------------------------------------------------
+def test_parse_trace_repeats_and_blank_tokens():
+    specs = parse_trace(" Q1, Q6x3,,Q3 ,")
+    assert [s.name for s in specs] == ["Q1", "Q6", "Q6", "Q6", "Q3"]
+
+
+def test_parse_trace_unknown_query_raises():
+    with pytest.raises(KeyError):
+        parse_trace("Q1,Q99")
+
+
+def test_serve_trace_matches_sequential_execute(db):
+    specs = parse_trace("Q1,Q6x2,Q14,Q3")
+    results, stats, wall = serve_trace(db, specs, concurrency=4,
+                                       max_window=4)
+    assert len(results) == len(specs) and wall > 0
+    assert stats["completed"] == len(specs) and stats["errors"] == 0
+    for spec, got in zip(specs, results):
+        want = db.execute(spec)
+        assert got.rows == want.rows, spec.name
+        assert got.aggregates == want.aggregates, spec.name
 
 
 # --------------------------------------------------------------------------
